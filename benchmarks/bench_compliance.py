@@ -60,6 +60,7 @@ from repro.kernels import ops
 from repro.parallel.sharding import UserShardSpec
 from repro.streaming import (Event, ShardedStreamingEngine, StateStore,
                              StoreConfig, StreamingEngine)
+from repro.launch.compile_cache import use_compile_cache
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import table2_predictive                                   # noqa: E402
@@ -439,4 +440,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

@@ -68,6 +68,7 @@ import numpy as np
 from repro.core import TifuParams, knn
 from repro.kernels import ops
 from repro.streaming import StateStore, StoreConfig, StreamingEngine
+from repro.launch.compile_cache import use_compile_cache
 
 from bench_update_batch import BACKEND_IMPL, merge_runs
 
@@ -447,4 +448,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

@@ -77,6 +77,7 @@ from repro.core.types import (KIND_ADD_BASKET, KIND_DEL_BASKET,
                               KIND_DEL_ITEM, KIND_NOOP, PAD_ID, AddBatch,
                               DelBasketBatch, DelItemBatch, UpdateBatch)
 from repro.kernels import ops
+from repro.launch.compile_cache import use_compile_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -730,4 +731,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

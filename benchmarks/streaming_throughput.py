@@ -7,6 +7,7 @@ import time
 
 from repro.data import stream, synthetic
 from repro.streaming import StateStore, StoreConfig, StreamingEngine
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run(batch_size: int, n_events: int = 4096, scale=0.01):
@@ -38,4 +39,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

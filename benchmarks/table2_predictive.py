@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.core import RefEngine, knn
 from repro.data import synthetic
+from repro.launch.compile_cache import use_compile_cache
 
 
 def evaluate(user_vecs: np.ndarray, users, test, params, ks=(10, 20)):
@@ -85,4 +86,5 @@ def main(scale=0.15):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

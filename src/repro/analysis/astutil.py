@@ -75,10 +75,15 @@ def _called_name(func: ast.expr) -> Optional[str]:
 
 @dataclasses.dataclass
 class BlockSpecInfo:
-    """One BlockSpec at a pallas_call site: index-map arity + location."""
+    """One BlockSpec at a pallas_call site: index-map arity + location.
+
+    ``unblocked`` marks a spec that names only a ``memory_space`` (an
+    operand the kernel reaches by DMA): it has no index map by design.
+    """
 
     arity: Optional[int]
     lineno: int
+    unblocked: bool = False
 
 
 @dataclasses.dataclass
@@ -109,22 +114,31 @@ def _spec_list(expr: Optional[ast.expr],
 
 def _block_spec_info(expr: ast.expr) -> BlockSpecInfo:
     arity: Optional[int] = None
+    unblocked = False
     if isinstance(expr, ast.Call) and _called_name(expr.func) == "BlockSpec":
         index_map: Optional[ast.expr] = None
         if len(expr.args) >= 2:
             index_map = expr.args[1]
+        kws = {kw.arg for kw in expr.keywords}
         for kw in expr.keywords:
             if kw.arg == "index_map":
                 index_map = kw.value
         if isinstance(index_map, ast.Lambda):
             arity = len(index_map.args.args)
-    return BlockSpecInfo(arity=arity, lineno=expr.lineno)
+        unblocked = (index_map is None and not expr.args
+                     and kws == {"memory_space"})
+    return BlockSpecInfo(arity=arity, lineno=expr.lineno,
+                         unblocked=unblocked)
 
 
 def _scratch_dtypes(expr: Optional[ast.expr],
                     env: Dict[str, ast.expr]) -> List[Optional[str]]:
     out: List[Optional[str]] = []
     for item in _spec_list(expr, env):
+        if (isinstance(item, ast.Call)
+                and isinstance(item.func, ast.Attribute)
+                and _called_name(item.func.value) == "SemaphoreType"):
+            continue       # a DMA/barrier semaphore is not a buffer
         dtype: Optional[str] = None
         if (isinstance(item, ast.Call) and len(item.args) >= 2
                 and _called_name(item.func) in ("VMEM", "SMEM", "ANY")):

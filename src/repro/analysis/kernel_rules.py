@@ -14,7 +14,7 @@ from typing import Dict, List, Optional
 from repro.analysis import astutil
 from repro.analysis.contracts import OOB_WRITE, KernelContract
 from repro.analysis.report import Finding
-from repro.analysis.vmem import VMEM_BUDGET_BYTES
+from repro.analysis.vmem import PIPELINE_BUFFERS, VMEM_BUDGET_BYTES, fits_vmem
 
 # Accumulation dtypes a kernel dot may declare (KC05): int8 operands
 # accumulate exactly in int32, everything else in f32.
@@ -57,6 +57,8 @@ def _check_grid_arity(site: astutil.PallasSite, c: KernelContract,
     for kind, specs in (("in_specs", site.in_specs),
                         ("out_specs", site.out_specs)):
         for i, spec in enumerate(specs):
+            if spec.unblocked:
+                continue
             if spec.arity is None:
                 out.append(_f("KC02", path, spec.lineno,
                               f"{site.entry}: {kind}[{i}] has no "
@@ -81,11 +83,11 @@ def _check_vmem(site: astutil.PallasSite, c: KernelContract,
         return [_f("KC03", path, site.lineno,
                    f"{site.entry}: vmem_model does not accept the "
                    f"declared max_shapes ({e})")]
-    if used > VMEM_BUDGET_BYTES:
+    if not fits_vmem(used):
         return [_f("KC03", path, site.lineno,
                    f"{site.entry}: model gives {used} bytes at max "
-                   f"shapes {dict(c.max_shapes)} > budget "
-                   f"{VMEM_BUDGET_BYTES}")]
+                   f"shapes {dict(c.max_shapes)}, x{PIPELINE_BUFFERS} "
+                   f"pipeline buffers > budget {VMEM_BUDGET_BYTES}")]
     return []
 
 

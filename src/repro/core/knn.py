@@ -26,8 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.kernels import ops
 
 
@@ -143,7 +143,6 @@ def distributed_predict(queries, corpus, k: int, alpha: float, mesh, rules,
     vs the natural item-TP formulation (psum of [Q, M] partial scores +
     row gathers): measured 26 GiB → <1 GiB collectives per device.
     """
-    from jax.sharding import PartitionSpec as P
     axes = tuple(a for a in ("pod", "data", "model")
                  if a in mesh.axis_names)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -176,7 +175,7 @@ def distributed_predict(queries, corpus, k: int, alpha: float, mesh, rules,
         nbr_sum = jax.lax.psum(partial, axes)
         return alpha * q + (1.0 - alpha) * nbr_sum / k
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P(axes, None)),
         out_specs=P(None, None), check_vma=False,
@@ -215,24 +214,75 @@ def recommend_topn(pred, n: int):
 
 
 def recommend_for_users(corpus, user_ids, k: int, alpha: float, topn: int,
-                        metric: str = "euclidean"):
+                        metric: str = "euclidean", n_items=None):
     """Fused serving path: corpus rows → top-n item ids (DESIGN.md §8).
 
     ``corpus`` is the (cached) materialized corpus f32[M, I]
     (``StateStore.corpus()``, DESIGN.md §3.6); ``user_ids`` i32[Q] are
     the requesting users, which are corpus rows (self-excluded from the
-    neighbourhood).  Dispatches through ``kernels.ops.fused_recommend``:
-    one compiled program per request batch shape — the engine-side pow2
-    request bucketing (`StreamingEngine.recommend`) bounds how many
-    such shapes serving ever compiles.  Returns i32[Q, topn] item ids.
+    neighbourhood).  ``n_items`` is the real catalogue: the store's lane
+    padding columns past it are never recommended.  Dispatches through
+    ``kernels.ops.fused_recommend``: one compiled program per request
+    batch shape — the engine-side pow2 request bucketing
+    (`StreamingEngine.recommend`) bounds how many such shapes serving
+    ever compiles.  Returns i32[Q, topn] item ids.
     """
     return ops.fused_recommend(corpus, user_ids, k=k, alpha=alpha,
-                               topn=topn, metric=metric)
+                               topn=topn, metric=metric, n_items=n_items)
 
 
 # ---------------------------------------------------------------------------
 # Cross-shard serving (user-axis sharded deployment, DESIGN.md §7)
 # ---------------------------------------------------------------------------
+
+def _placement(x):
+    """A sharding that puts a new array on the devices holding ``x``."""
+    sh = x.sharding
+    if isinstance(sh, NamedSharding):
+        return NamedSharding(sh.mesh, P())
+    return sh
+
+
+@jax.jit
+def _take_rows(array, rows):
+    return jnp.take(array, rows, axis=0)
+
+
+def _gather_owned_rows(arrays, gids, n_shards: int, target):
+    """Rows ``gids`` (global user ids) of per-shard ``arrays``, on ``target``.
+
+    Shard ``s`` owns global user ``r·n_shards + s`` as its local row
+    ``r``; each shard gathers its rows on its own devices and only those
+    rows leave it (through the host, where they are placed in request
+    order).  Row lists are padded to a power of two so the gathers
+    compile once per size bucket.  Returns ``gids.shape + row shape``.
+    """
+    gids = np.asarray(gids, np.int64)
+    flat = gids.ravel()
+    tail = arrays[0].shape[1:]
+    out = np.empty((flat.size,) + tail, arrays[0].dtype)
+    for s, arr in enumerate(arrays):
+        pos = np.nonzero(flat % n_shards == s)[0]
+        if pos.size == 0:
+            continue
+        local = (flat[pos] // n_shards).astype(np.int32)
+        local = np.resize(local, 1 << (pos.size - 1).bit_length())
+        rows = _take_rows(arr, jax.device_put(local, _placement(arr)))
+        out[pos] = np.asarray(rows)[:pos.size]
+    return jax.device_put(out.reshape(gids.shape + tail), target)
+
+
+def _merge_candidates(vals, gids, k: int) -> np.ndarray:
+    """Global top-k ids from per-shard candidate lists, on host.
+
+    Score descending, then global id ascending — `jax.lax.top_k`'s
+    tie-break on a single corpus.
+    """
+    all_vals = np.concatenate([np.asarray(v) for v in vals], axis=1)
+    all_gids = np.concatenate([np.asarray(g) for g in gids], axis=1)
+    order = np.lexsort((all_gids, -all_vals), axis=-1)
+    return np.take_along_axis(all_gids, order, axis=1)[:, :k]
+
 
 def shard_topk_candidates(queries, corpus, k: int, shard: int,
                           n_shards: int, query_ids=None,
@@ -256,61 +306,47 @@ def shard_topk_candidates(queries, corpus, k: int, shard: int,
 
 def sharded_recommend_for_users(corpora, user_ids, k: int, alpha: float,
                                 topn: int, n_shards: int,
-                                metric: str = "euclidean") -> np.ndarray:
+                                metric: str = "euclidean",
+                                n_items=None) -> np.ndarray:
     """Distributed TIFU-kNN serving over per-shard corpora (§7).
 
-    Pipeline: (1) gather query rows from their owner shards; (2) each
-    shard scores queries against only its local corpus and returns its
-    top-k candidate ``(score, global id)`` lists; (3) a streaming merge
-    takes the global top-k — candidates are ordered by (score desc,
-    global id asc), exactly `jax.lax.top_k`'s tie-break on a single
-    corpus, so the selected neighbour set and order match the unsharded
-    path bitwise; (4) only the k selected neighbour ROWS are fetched
-    (O(Q·k·I), never a corpus) and blended exactly as
-    `recommend_for_users` does (``kernels.ops.blend_topn_rows`` — on
-    TPU the fused mean/blend/top-n kernel, no [Q, I] prediction
-    intermediate).  Cross-shard traffic is the [Q, k] candidate lists
-    plus the selected rows — corpora and row invalidation stay
-    shard-local (`StateStore.corpus`).
+    Pipeline: (1) each owner shard gathers its query rows on its own
+    devices; (2) every shard scores the queries against only its local
+    corpus, on its own devices, and returns its top-k candidate
+    ``(score, global id)`` lists; (3) a host merge takes the global
+    top-k — candidates are ordered by (score desc, global id asc),
+    exactly `jax.lax.top_k`'s tie-break on a single corpus; (4) only the
+    k selected neighbour ROWS move, from their owner shards to shard 0's
+    devices, where they are blended (``kernels.ops.blend_topn_rows`` —
+    on TPU the fused mean/blend/top-n kernel).  Cross-device traffic is
+    the query rows, the [Q, k] candidate lists and the selected rows;
+    corpora never leave their shards.  ``n_items`` as in
+    :func:`recommend_for_users`.
 
-    Returns i32[Q, topn] item ids, bitwise-identical to
-    ``recommend_for_users`` on the equivalent single corpus
-    (tests/test_sharded_engine.py pins this).
+    Returns i32[Q, topn] item ids.  Per-pair scores do not depend on
+    the partition, so any shard count gives the same answer
+    (tests/test_sharded_engine.py pins it against the single-corpus
+    path).
     """
     user_ids = np.asarray(user_ids, np.int64)
-    corpora_np = [np.asarray(c) for c in corpora]
-    q_n = user_ids.shape[0]
-    n_items = corpora_np[0].shape[1]
-    queries = np.empty((q_n, n_items), corpora_np[0].dtype)
-    for s in range(n_shards):
-        m = user_ids % n_shards == s
-        if m.any():
-            queries[m] = corpora_np[s][user_ids[m] // n_shards]
-    qs = jnp.asarray(queries)
-    qids = jnp.asarray(user_ids.astype(np.int32))
-    vals, gids = [], []
-    for s in range(n_shards):
-        v, g = shard_topk_candidates(qs, corpora[s], k, s, n_shards,
-                                     query_ids=qids, metric=metric)
-        vals.append(np.asarray(v))
-        gids.append(np.asarray(g))
-    all_vals = np.concatenate(vals, axis=1)
-    all_gids = np.concatenate(gids, axis=1)
-    # merge: score desc, global id asc — lax.top_k's tie-break order
-    order = np.lexsort((all_gids, -all_vals), axis=-1)
-    sel = np.take_along_axis(all_gids, order, axis=1)[:, :k]
-    neighbor_rows = np.empty((q_n, sel.shape[1], n_items),
-                             corpora_np[0].dtype)
-    for s in range(n_shards):
-        m = sel % n_shards == s
-        if m.any():
-            neighbor_rows[m] = corpora_np[s][sel[m] // n_shards]
-    return np.asarray(ops.blend_topn_rows(qs, jnp.asarray(neighbor_rows),
-                                          alpha, topn))
+    target = _placement(corpora[0])
+    queries = _gather_owned_rows(corpora, user_ids, n_shards, target)
+    qids = user_ids.astype(np.int32)
+    cands = []
+    for s, corpus in enumerate(corpora):     # dispatch every shard first
+        place = _placement(corpus)
+        cands.append(shard_topk_candidates(
+            jax.device_put(queries, place), corpus, k, s, n_shards,
+            query_ids=jax.device_put(qids, place), metric=metric))
+    sel = _merge_candidates([v for v, _ in cands], [g for _, g in cands], k)
+    neighbor_rows = _gather_owned_rows(corpora, sel, n_shards, target)
+    return np.asarray(ops.blend_topn_rows(queries, neighbor_rows, alpha,
+                                          topn, n_items=n_items))
 
 
 def recommend_for_users_quant(corpus_q, c_scale, user_ids, k: int,
-                              alpha: float, topn: int, bd: int = 512):
+                              alpha: float, topn: int, bd: int = 512,
+                              n_items=None):
     """Int8 fused serving (DESIGN.md §8.4): quantized corpus → top-n ids.
 
     The million-item twin of :func:`recommend_for_users`: ``corpus_q``
@@ -323,13 +359,14 @@ def recommend_for_users_quant(corpus_q, c_scale, user_ids, k: int,
     i32[Q, topn] item ids.
     """
     return ops.fused_recommend_quant(corpus_q, c_scale, user_ids, k=k,
-                                     alpha=alpha, topn=topn, bd=bd)
+                                     alpha=alpha, topn=topn, bd=bd,
+                                     n_items=n_items)
 
 
 def sharded_recommend_for_users_quant(quant_corpora, user_ids, k: int,
                                       alpha: float, topn: int,
-                                      n_shards: int,
-                                      bd: int = 512) -> np.ndarray:
+                                      n_shards: int, bd: int = 512,
+                                      n_items=None) -> np.ndarray:
     """Distributed int8 serving over per-shard quantized corpora (§8.4).
 
     Same four-stage pipeline as :func:`sharded_recommend_for_users`,
@@ -342,45 +379,29 @@ def sharded_recommend_for_users_quant(quant_corpora, user_ids, k: int,
     (score desc, global id asc) selects the same neighbour set and the
     result is bitwise ``recommend_for_users_quant`` on the equivalent
     single corpus (tests/test_quantized_serving.py pins this).
-    Cross-shard traffic: [Q, k] candidates + the selected rows — int8,
-    ¼ the fp32 path's row-fetch bytes.
+    Cross-device traffic: query rows, [Q, k] candidates and the
+    selected rows — int8, ¼ the fp32 path's row-fetch bytes.
     """
     user_ids = np.asarray(user_ids, np.int64)
-    corpora_np = [np.asarray(q) for q, _ in quant_corpora]
-    scales_np = [np.asarray(s) for _, s in quant_corpora]
-    q_n = user_ids.shape[0]
-    n_items = corpora_np[0].shape[1]
-    queries_q = np.empty((q_n, n_items), np.int8)
-    q_scale = np.empty((q_n,), np.float32)
-    for s in range(n_shards):
-        m = user_ids % n_shards == s
-        if m.any():
-            queries_q[m] = corpora_np[s][user_ids[m] // n_shards]
-            q_scale[m] = scales_np[s][user_ids[m] // n_shards]
-    qs_j = jnp.asarray(queries_q)
-    qscale_j = jnp.asarray(q_scale)
-    qids = jnp.asarray(user_ids.astype(np.int32))
-    vals, gids = [], []
+    corpora = [q for q, _ in quant_corpora]
+    scales = [sc[:, None] for _, sc in quant_corpora]
+    target = _placement(corpora[0])
+    queries_q = _gather_owned_rows(corpora, user_ids, n_shards, target)
+    q_scale = _gather_owned_rows(scales, user_ids, n_shards, target)[:, 0]
+    qids = user_ids.astype(np.int32)
+    cands = []
     for s, (cq, cs) in enumerate(quant_corpora):
-        v, g = ops.shard_topk_quant(qs_j, qscale_j, cq, cs, k, shard=s,
-                                    n_shards=n_shards, query_gids=qids,
-                                    bd=bd)
-        vals.append(np.asarray(v))
-        gids.append(np.asarray(g))
-    all_vals = np.concatenate(vals, axis=1)
-    all_gids = np.concatenate(gids, axis=1)
-    order = np.lexsort((all_gids, -all_vals), axis=-1)
-    sel = np.take_along_axis(all_gids, order, axis=1)[:, :k]
-    neighbor_q = np.empty((q_n, sel.shape[1], n_items), np.int8)
-    n_scale = np.empty((q_n, sel.shape[1]), np.float32)
-    for s in range(n_shards):
-        m = sel % n_shards == s
-        if m.any():
-            neighbor_q[m] = corpora_np[s][sel[m] // n_shards]
-            n_scale[m] = scales_np[s][sel[m] // n_shards]
+        place = _placement(cq)
+        cands.append(ops.shard_topk_quant(
+            jax.device_put(queries_q, place), jax.device_put(q_scale, place),
+            cq, cs, k, shard=s, n_shards=n_shards,
+            query_gids=jax.device_put(qids, place), bd=bd))
+    sel = _merge_candidates([v for v, _ in cands], [g for _, g in cands], k)
+    neighbor_q = _gather_owned_rows(corpora, sel, n_shards, target)
+    n_scale = _gather_owned_rows(scales, sel, n_shards, target)[..., 0]
     return np.asarray(ops.blend_topn_rows_quant(
-        qs_j, qscale_j, jnp.asarray(neighbor_q), jnp.asarray(n_scale),
-        alpha, topn))
+        queries_q, q_scale, neighbor_q, n_scale, alpha, topn,
+        n_items=n_items))
 
 
 # ---------------------------------------------------------------------------

@@ -17,13 +17,14 @@ Two state representations coexist (see DESIGN.md §3):
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, List, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 PAD_ID = -1  # padding value for item ids in basket arrays
+LANE = 128   # TPU vector lane width: the dense item axis is padded to it
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,6 +122,11 @@ class StreamState:
       uv_scale:        f32[M]
       lgv_scale:       f32[M]
 
+    The item axis ``I`` is the catalogue padded up to the 128-lane tile
+    (``n_cols``); ``n_real`` records the catalogue itself (static, not a
+    leaf).  Pad columns are never written — ids are validated against
+    the catalogue — so they stay exactly zero and change no distance.
+
     Scaled representation (DESIGN.md §3.3): the *true* TIFU vectors are
 
         user_vec(u)       = uv_scale[u]  * user_vecs[u]
@@ -144,27 +150,35 @@ class StreamState:
     err_mult: jax.Array
     uv_scale: jax.Array
     lgv_scale: jax.Array
+    # catalogue size; None: every column of the dense leaves is an item
+    n_real: Optional[int] = None
 
-    def tree_flatten(self) -> Tuple[Tuple[jax.Array, ...], None]:
+    def tree_flatten(self) -> Tuple[Tuple[jax.Array, ...], Optional[int]]:
         children = (self.user_vecs, self.last_group_vecs, self.history,
                     self.group_sizes, self.n_baskets, self.n_groups,
                     self.err_mult, self.uv_scale, self.lgv_scale)
-        return children, None
+        return children, self.n_real
 
     @classmethod
-    def tree_unflatten(cls, aux: None,
+    def tree_unflatten(cls, aux: Optional[int],
                        children: Tuple[jax.Array, ...]) -> "StreamState":
-        return cls(*children)
+        return cls(*children, n_real=aux)
 
     # -- true-value accessors -------------------------------------------------
 
-    def materialized_user_vecs(self) -> jax.Array:
-        """True user vectors f32[M, I] (raw rows × per-user scale)."""
-        return self.user_vecs * self.uv_scale[:, None]
+    def materialized_user_vecs(self, padded: bool = False) -> jax.Array:
+        """True user vectors f32[M, n_items] (raw rows × per-user scale).
+
+        ``padded=True`` keeps the zero pad columns: f32[M, n_cols], the
+        lane-aligned serving corpus (``StateStore.corpus``).
+        """
+        out = self.user_vecs * self.uv_scale[:, None]
+        return out if padded else out[:, :self.n_items]
 
     def materialized_last_group_vecs(self) -> jax.Array:
-        """True last-group vectors f32[M, I]."""
-        return self.last_group_vecs * self.lgv_scale[:, None]
+        """True last-group vectors f32[M, n_items]."""
+        return (self.last_group_vecs
+                * self.lgv_scale[:, None])[:, :self.n_items]
 
     @property
     def n_users(self) -> int:
@@ -172,6 +186,12 @@ class StreamState:
 
     @property
     def n_items(self) -> int:
+        """Catalogue size (the real item columns)."""
+        return self.n_cols if self.n_real is None else self.n_real
+
+    @property
+    def n_cols(self) -> int:
+        """Width of the dense leaves: the catalogue plus lane padding."""
         return self.user_vecs.shape[1]
 
     @property
@@ -190,11 +210,13 @@ class StreamState:
     def zeros(n_users: int, n_items: int, max_baskets: int,
               max_basket_size: int, max_groups: int | None = None,
               dtype: Any = jnp.float32) -> "StreamState":
+        """Empty state for a catalogue of ``n_items``, lane-padded."""
         if max_groups is None:
             max_groups = max_baskets  # worst case: all groups of size 1
+        n_cols = lane_padded(n_items)
         return StreamState(
-            user_vecs=jnp.zeros((n_users, n_items), dtype),
-            last_group_vecs=jnp.zeros((n_users, n_items), dtype),
+            user_vecs=jnp.zeros((n_users, n_cols), dtype),
+            last_group_vecs=jnp.zeros((n_users, n_cols), dtype),
             history=jnp.full((n_users, max_baskets, max_basket_size), PAD_ID,
                              jnp.int32),
             group_sizes=jnp.zeros((n_users, max_groups), jnp.int32),
@@ -203,7 +225,13 @@ class StreamState:
             err_mult=jnp.ones((n_users,), dtype),
             uv_scale=jnp.ones((n_users,), dtype),
             lgv_scale=jnp.ones((n_users,), dtype),
+            n_real=n_items,
         )
+
+
+def lane_padded(n_items: int) -> int:
+    """``n_items`` rounded up to the 128-lane tile of the TPU."""
+    return -(-n_items // LANE) * LANE
 
 
 # Update kinds for the streaming engine (Algorithm 1 generalised).

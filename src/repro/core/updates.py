@@ -31,6 +31,7 @@ compiled program serves every deletion position.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -212,10 +213,11 @@ def _delete_basket(user_vec, last_group_vec, history, group_sizes, n_baskets,
         jnp.full((history.shape[1],), PAD_ID, jnp.int32))
     n_baskets = n_baskets - 1
 
-    # last_group_vec: recompute from the new geometry (cheap, masked).
+    # last_group_vec: recompute from the new geometry (cheap, masked),
+    # at the row's (lane-padded) width
     last_group_vec = last_group_vector_padded(
         history, group_sizes, n_groups,
-        params).astype(f32)
+        dataclasses.replace(params, n_items=user_vec.shape[0])).astype(f32)
     return (user_vec, last_group_vec, history, group_sizes, n_baskets,
             n_groups, err_mult)
 
@@ -448,6 +450,7 @@ def _apply_add_batch(state: StreamState, batch: AddBatch,
 
     dropped = jnp.sum((at_capacity & batch.valid).astype(jnp.int32))
     return StreamState(
+        n_real=state.n_real,
         user_vecs=user_vecs,
         last_group_vecs=lg_vecs,
         history=state.history.at[u, hist_slot].add(hist_delta),
@@ -523,6 +526,7 @@ def _scatter_del_deltas(state: StreamState, u, valid, old, new):
     dlgv = jnp.where(vf, n_lgv / sig[:, None] - state.last_group_vecs[u],
                      0.0)
     return StreamState(
+        n_real=state.n_real,
         user_vecs=state.user_vecs.at[u].add(duv),
         last_group_vecs=state.last_group_vecs.at[u].add(dlgv),
         history=state.history.at[u].add(
@@ -736,6 +740,7 @@ def apply_del_basket_batch(state: StreamState, batch: DelBasketBatch,
                                      pos, valid, params, t_max_cap)
     vf = valid[:, None]
     return StreamState(
+        n_real=state.n_real,
         user_vecs=sparse_row_scatter(state.user_vecs, u, ids, u_vals,
                                      t_max_cap=t_max_cap),
         last_group_vecs=sparse_row_scatter(state.last_group_vecs, u, ids,
@@ -815,6 +820,7 @@ def apply_del_item_batch(state: StreamState, batch: DelItemBatch,
     new_gs = jnp.where(apply_db[:, None], gs_db, gs)
     touched = apply_db | apply_ip
     return StreamState(
+        n_real=state.n_real,
         user_vecs=sparse_row_scatter(state.user_vecs, u, ids, u_vals,
                                      t_max_cap=t_max_cap),
         last_group_vecs=sparse_row_scatter(state.last_group_vecs, u, ids,
@@ -898,6 +904,7 @@ def apply_update_batch_dense(state: StreamState, batch: UpdateBatch,
         batch.item)
     deltas = tuple(new - old for new, old in zip(updated, gathered))
     return StreamState(
+        n_real=state.n_real,
         user_vecs=state.user_vecs.at[u].add(deltas[0] / s[:, None]),
         last_group_vecs=state.last_group_vecs.at[u].add(
             deltas[1] / sig[:, None]),
@@ -926,11 +933,14 @@ def refresh_users(state: StreamState, users, params: TifuParams) -> StreamState:
     h = state.history[users]
     gs = state.group_sizes[users]
     ng = state.n_groups[users]
+    # rebuild at the leaves' padded width: pad columns come out zero
+    params = dataclasses.replace(params, n_items=state.n_cols)
     fresh = jax.vmap(lambda hh, gg, nn: user_vector_padded(hh, gg, nn, params))(
         h, gs, ng).astype(state.user_vecs.dtype)
     lgv = jax.vmap(lambda hh, gg, nn: last_group_vector_padded(
         hh, gg, nn, params))(h, gs, ng).astype(state.user_vecs.dtype)
     return StreamState(
+        n_real=state.n_real,
         user_vecs=state.user_vecs.at[users].set(fresh),
         last_group_vecs=state.last_group_vecs.at[users].set(lgv),
         history=state.history,
@@ -955,6 +965,7 @@ def renormalize_users(state: StreamState, users) -> StreamState:
     s = state.uv_scale[users]
     sig = state.lgv_scale[users]
     return StreamState(
+        n_real=state.n_real,
         user_vecs=state.user_vecs.at[users].multiply(s[:, None]),
         last_group_vecs=state.last_group_vecs.at[users].multiply(
             sig[:, None]),

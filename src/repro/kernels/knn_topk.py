@@ -25,11 +25,11 @@ Design (DESIGN.md §3.4 / §8):
   sharded corpus (DESIGN.md §7.1), so a query user is excluded only on
   its owner shard.
 
-  The merge uses lax.top_k on the concatenated [bq, k+bm] tile, which
-  preserves lax.top_k's tie-break (lowest index wins): the running
-  buffer holds candidates from earlier (lower-id) tiles and sits first
-  in the concat, so an equal-score later column never displaces an
-  earlier one (pinned by tests/test_serving_pipeline.py).
+  The merge is ``kernels.topk_merge.merge_topk`` (Mosaic has no
+  ``top_k``): k extraction passes over the running list and the score
+  tile in lax.top_k's order — score descending, lowest index first on
+  ties (pinned by tests/test_serving_pipeline.py).  Stage A runs its
+  f32 contractions at HIGHEST precision.
 
 D-tiled variant (DESIGN.md §8.4): ``knn_topk`` holds full [bq, D] /
 [bm, D] blocks in VMEM — an O(bq·D) residency that walls out at
@@ -58,26 +58,44 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis import vmem as _avmem
 from repro.analysis.contracts import OOB_WRITE, KernelContract, register
+from repro.kernels.topk_merge import init_topk, merge_topk
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(qid_ref, q_ref, c_ref, cn_ref, vals_ref, idx_ref, acc_vals,
-            acc_idx, *, k: int, bm: int, metric: str, m: int,
+def _corpus_sqnorm(c):
+    """|c|² of each corpus row in a block, as a [1, bm] row, on the MXU.
+
+    One fixed contraction per row whichever rows share the block, so a
+    row's norm — and every score — does not depend on how the corpus is
+    partitioned across shards; an XLA reduction's order depends on the
+    array's shape.  Exact for int8 rows (integer squares < 2¹⁴, sums of
+    one D-tile < 2²⁴).
+    """
+    cf = c.astype(jnp.float32)
+    return jax.lax.dot_general(
+        jnp.ones((1, c.shape[1]), jnp.float32), cf * cf,
+        (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _kernel(qid_ref, q_ref, c_ref, vals_ref, idx_ref, tile, acc_vals, acc_idx,
+            new_vals, new_idx, *, k: int, bm: int, metric: str, m: int,
             col_offset: int, col_stride: int, sub_qnorm: bool):
     mi = pl.program_id(1)
     nm = pl.num_programs(1)
 
     @pl.when(mi == 0)
     def _init():
-        acc_vals[...] = jnp.full_like(acc_vals, -jnp.inf)
-        acc_idx[...] = jnp.zeros_like(acc_idx)
+        init_topk(acc_vals, acc_idx)
 
     q = q_ref[...]                                   # [bq, D]
     c = c_ref[...]                                   # [bm, D]
     scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())),
+        q, c, (((1,), (1,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32)          # [bq, bm]
     if metric == "euclidean":
-        scores = 2.0 * scores - cn_ref[...][None, :]
+        scores = 2.0 * scores - _corpus_sqnorm(c)
         if sub_qnorm:
             # full −|q−c|²: the shard-candidate path emits these scores
             # into the cross-shard merge, where they must be the same
@@ -92,13 +110,10 @@ def _kernel(qid_ref, q_ref, c_ref, cn_ref, vals_ref, idx_ref, acc_vals,
     scores = jnp.where(tile_idx >= m, -jnp.inf, scores)
     # fused self-exclusion on GLOBAL ids (qid = -1 disables: gids >= 0)
     col_gid = tile_idx * col_stride + col_offset
-    scores = jnp.where(col_gid == qid_ref[...][:, None], -jnp.inf, scores)
+    scores = jnp.where(col_gid == qid_ref[...], -jnp.inf, scores)
 
-    merged_vals = jnp.concatenate([acc_vals[...], scores], axis=1)
-    merged_idx = jnp.concatenate([acc_idx[...], tile_idx], axis=1)
-    top_vals, top_pos = jax.lax.top_k(merged_vals, k)
-    acc_vals[...] = top_vals
-    acc_idx[...] = jnp.take_along_axis(merged_idx, top_pos, axis=1)
+    tile[...] = scores
+    merge_topk(acc_vals, acc_idx, new_vals, new_idx, tile, mi * bm, k)
 
     @pl.when(mi == nm - 1)
     def _done():
@@ -135,8 +150,9 @@ def knn_topk(queries, corpus, k: int, bq: int = 128, bm: int = 512,
     bm = min(bm, m)
     if query_gids is None:
         query_gids = jnp.full((qn,), -1, jnp.int32)
-    cnorm = jnp.sum(corpus.astype(jnp.float32) ** 2, axis=-1)
     grid = (pl.cdiv(qn, bq), pl.cdiv(m, bm))
+    # per-row side vectors travel as [Q, 1] columns / [1, M] rows: a 1-D
+    # block does not match the chip's layout for a 1-D operand
     kernel = functools.partial(_kernel, k=k, bm=bm, metric=metric, m=m,
                                col_offset=col_offset, col_stride=col_stride,
                                sub_qnorm=sub_qnorm)
@@ -144,10 +160,9 @@ def knn_topk(queries, corpus, k: int, bq: int = 128, bm: int = 512,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq,), lambda qi, mi: (qi,)),
+            pl.BlockSpec((bq, 1), lambda qi, mi: (qi, 0)),
             pl.BlockSpec((bq, d), lambda qi, mi: (qi, 0)),
             pl.BlockSpec((bm, d), lambda qi, mi: (mi, 0)),
-            pl.BlockSpec((bm,), lambda qi, mi: (mi,)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda qi, mi: (qi, 0)),
@@ -158,11 +173,14 @@ def knn_topk(queries, corpus, k: int, bq: int = 128, bm: int = 512,
             jax.ShapeDtypeStruct((qn, k), jnp.int32),
         ],
         scratch_shapes=[
+            pltpu.VMEM((bq, bm), jnp.float32),  # score tile
             pltpu.VMEM((bq, k), jnp.float32),   # running top-k vals
             pltpu.VMEM((bq, k), jnp.int32),     # running top-k idx
+            pltpu.VMEM((bq, k), jnp.float32),   # merge output vals
+            pltpu.VMEM((bq, k), jnp.int32),     # merge output idx
         ],
         interpret=interpret,
-    )(query_gids.astype(jnp.int32), queries, corpus, cnorm)
+    )(query_gids.astype(jnp.int32)[:, None], queries, corpus)
 
 
 # ---------------------------------------------------------------------------
@@ -196,8 +214,9 @@ def tiled_sqnorm(x, bd: int):
     return jnp.cumsum(per_tile, axis=1)[:, -1]
 
 
-def _dtiled_kernel(qid_ref, q_ref, c_ref, cn_ref, qn_ref, qs_ref, cs_ref,
-                   vals_ref, idx_ref, acc, acc_vals, acc_idx, *, k: int,
+def _dtiled_kernel(qid_ref, q_ref, c_ref, qn_ref, qs_ref, cs_ref, vals_ref,
+                   idx_ref, acc, cn, acc_vals, acc_idx, new_vals, new_idx, *,
+                   k: int,
                    bm: int, bd: int, m: int, d: int, col_offset: int,
                    col_stride: int, sub_qnorm: bool, quantized: bool):
     mi = pl.program_id(1)
@@ -207,12 +226,12 @@ def _dtiled_kernel(qid_ref, q_ref, c_ref, cn_ref, qn_ref, qs_ref, cs_ref,
 
     @pl.when((mi == 0) & (di == 0))
     def _init_topk():
-        acc_vals[...] = jnp.full_like(acc_vals, -jnp.inf)
-        acc_idx[...] = jnp.zeros_like(acc_idx)
+        init_topk(acc_vals, acc_idx)
 
     @pl.when(di == 0)
     def _init_acc():
         acc[...] = jnp.zeros_like(acc)
+        cn[...] = jnp.zeros_like(cn)
 
     q = q_ref[...]                                   # [bq, bd]
     c = c_ref[...]                                   # [bm, bd]
@@ -221,6 +240,7 @@ def _dtiled_kernel(qid_ref, q_ref, c_ref, cn_ref, qn_ref, qs_ref, cs_ref,
     lane = di * bd + jax.lax.broadcasted_iota(jnp.int32, (1, bd), 1)
     q = jnp.where(lane < d, q, jnp.zeros_like(q))
     c = jnp.where(lane < d, c, jnp.zeros_like(c))
+    cn[...] += _corpus_sqnorm(c)                     # [1, bm]
     if quantized:
         # exact int32 partial per tile; f32 convert exact for bd <= 1024
         part = jax.lax.dot_general(
@@ -229,28 +249,23 @@ def _dtiled_kernel(qid_ref, q_ref, c_ref, cn_ref, qn_ref, qs_ref, cs_ref,
         acc[...] += part.astype(jnp.float32)
     else:
         acc[...] += jax.lax.dot_general(
-            q, c, (((1,), (1,)), ((), ())),
+            q, c, (((1,), (1,)), ((), ())), precision=_HIGHEST,
             preferred_element_type=jnp.float32)
 
     @pl.when(di == nd - 1)
     def _merge():
-        qs = qs_ref[...]                             # [bq]
-        cs = cs_ref[...]                             # [bm]
-        scores = (2.0 * (qs[:, None] * cs[None, :]) * acc[...]
-                  - (cs * cs)[None, :] * cn_ref[...][None, :])
+        qs = qs_ref[...]                             # [bq, 1]
+        cs = cs_ref[...]                             # [1, bm]
+        scores = (2.0 * (qs * cs) * acc[...] - (cs * cs) * cn[...])
         if sub_qnorm:
-            scores = scores - (qs * qs * qn_ref[...])[:, None]
+            scores = scores - qs * qs * qn_ref[...]
         tile_idx = mi * bm + jax.lax.broadcasted_iota(jnp.int32,
                                                       scores.shape, 1)
         scores = jnp.where(tile_idx >= m, -jnp.inf, scores)
         col_gid = tile_idx * col_stride + col_offset
-        scores = jnp.where(col_gid == qid_ref[...][:, None], -jnp.inf,
-                           scores)
-        merged_vals = jnp.concatenate([acc_vals[...], scores], axis=1)
-        merged_idx = jnp.concatenate([acc_idx[...], tile_idx], axis=1)
-        top_vals, top_pos = jax.lax.top_k(merged_vals, k)
-        acc_vals[...] = top_vals
-        acc_idx[...] = jnp.take_along_axis(merged_idx, top_pos, axis=1)
+        scores = jnp.where(col_gid == qid_ref[...], -jnp.inf, scores)
+        acc[...] = scores
+        merge_topk(acc_vals, acc_idx, new_vals, new_idx, acc, mi * bm, k)
 
     @pl.when((mi == nm - 1) & (di == nd - 1))
     def _done():
@@ -304,7 +319,6 @@ def knn_topk_dtiled(queries, corpus, k: int, bq: int = 128, bm: int = 512,
     bd = min(bd, d)
     if query_gids is None:
         query_gids = jnp.full((qn_,), -1, jnp.int32)
-    cnorm = tiled_sqnorm(corpus, bd)
     qnorm = (tiled_sqnorm(queries, bd) if sub_qnorm
              else jnp.zeros((qn_,), jnp.float32))
     if q_scale is None:
@@ -320,13 +334,12 @@ def knn_topk_dtiled(queries, corpus, k: int, bq: int = 128, bm: int = 512,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq,), lambda qi, mi, di: (qi,)),
+            pl.BlockSpec((bq, 1), lambda qi, mi, di: (qi, 0)),
             pl.BlockSpec((bq, bd), lambda qi, mi, di: (qi, di)),
             pl.BlockSpec((bm, bd), lambda qi, mi, di: (mi, di)),
-            pl.BlockSpec((bm,), lambda qi, mi, di: (mi,)),
-            pl.BlockSpec((bq,), lambda qi, mi, di: (qi,)),
-            pl.BlockSpec((bq,), lambda qi, mi, di: (qi,)),
-            pl.BlockSpec((bm,), lambda qi, mi, di: (mi,)),
+            pl.BlockSpec((bq, 1), lambda qi, mi, di: (qi, 0)),
+            pl.BlockSpec((bq, 1), lambda qi, mi, di: (qi, 0)),
+            pl.BlockSpec((1, bm), lambda qi, mi, di: (0, mi)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda qi, mi, di: (qi, 0)),
@@ -338,12 +351,15 @@ def knn_topk_dtiled(queries, corpus, k: int, bq: int = 128, bm: int = 512,
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, bm), acc_dtype),    # running q·cᵀ partial
+            pltpu.VMEM((1, bm), jnp.float32),   # running |c|² partial
             pltpu.VMEM((bq, k), jnp.float32),   # running top-k vals
             pltpu.VMEM((bq, k), jnp.int32),     # running top-k idx
+            pltpu.VMEM((bq, k), jnp.float32),   # merge output vals
+            pltpu.VMEM((bq, k), jnp.int32),     # merge output idx
         ],
         interpret=interpret,
-    )(query_gids.astype(jnp.int32), queries, corpus, cnorm, qnorm,
-      q_scale, c_scale)
+    )(query_gids.astype(jnp.int32)[:, None], queries, corpus, qnorm[:, None],
+      q_scale[:, None], c_scale[None, :])
 
 
 # Kernel contracts (DESIGN.md §10.1): what tools/lint_kernels.py holds
@@ -355,9 +371,9 @@ register(KernelContract(
     body="_kernel",
     grid_rank=2,
     tail={0: OOB_WRITE, 1: "tile_idx >= m"},
-    accumulators=("float32", "int32"),
+    accumulators=("float32", "float32", "int32", "float32", "int32"),
     vmem_model=_avmem.knn_topk_block_bytes,
-    max_shapes={"d": 4096, "k": 512, "bq": 128, "bm": 512},
+    max_shapes={"d": 2048, "k": 300, "bq": 128, "bm": 512},
 ))
 register(KernelContract(
     module="repro.kernels.knn_topk",
@@ -365,7 +381,8 @@ register(KernelContract(
     body="_dtiled_kernel",
     grid_rank=3,
     tail={0: OOB_WRITE, 1: "tile_idx >= m", 2: "lane < d"},
-    accumulators=("float32", "float32", "int32"),
+    accumulators=("float32", "float32", "float32", "int32", "float32",
+                  "int32"),
     vmem_model=_avmem.knn_topk_dtiled_block_bytes,
     max_shapes={"d": 1 << 20, "k": 512, "bq": 128, "bm": 512,
                 "bd": 512},

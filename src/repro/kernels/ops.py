@@ -1,9 +1,13 @@
-"""Jit'd dispatch wrappers: Pallas on TPU, interpret/reference on CPU.
+"""Jit'd dispatch wrappers: Pallas on TPU, reference on CPU.
 
 The public entry points the rest of the system calls; each picks the
-fastest implementation available for the current backend and is
-guaranteed (by tests/test_kernels.py shape/dtype sweeps) to match the
-ref.py oracles.
+implementation for the current backend and is guaranteed (by
+tests/test_kernels.py shape/dtype sweeps) to match the ref.py oracles.
+``impl="auto"`` runs the compiled Pallas kernel on a TPU and the XLA
+reference elsewhere; ``"pallas"`` demands the compiled kernel and raises
+off a TPU; ``"interpret"`` runs the kernel bodies in the Pallas
+interpreter on any host; ``"ref"`` runs the XLA reference
+(:func:`resolve_impl`).
 
 ``default_impl`` overrides the per-call default process-wide — the
 benchmark's ``--backend interpret`` arm and the interpret-mode stream
@@ -58,12 +62,46 @@ def default_impl(impl: str) -> Iterator[None]:
         jax.clear_caches()
 
 
-def _resolve(impl: Optional[str]) -> str:
-    return _DEFAULT_IMPL if impl is None else impl
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def resolve_impl(impl: Optional[str]) -> str:
+    """The implementation a call with ``impl`` runs here.
+
+    Returns ``"pallas"`` (compiled kernel), ``"interpret"`` (kernel body
+    in the Pallas interpreter) or ``"ref"`` (XLA reference).  ``None``
+    reads the :func:`default_impl` override.  Raises when the compiled
+    kernel is demanded (``"pallas"``) off a TPU: interpret mode is only
+    ever chosen explicitly.
+    """
+    impl = _DEFAULT_IMPL if impl is None else impl
+    if impl == "auto":
+        return "pallas" if _on_tpu() else "ref"
+    if impl == "pallas" and not _on_tpu():
+        raise RuntimeError(
+            f"impl='pallas' needs a TPU backend, found "
+            f"{jax.default_backend()!r}; use impl='interpret' to run the "
+            "kernel bodies in the Pallas interpreter")
+    if impl not in ("pallas", "interpret", "ref"):
+        raise ValueError(f"unknown impl {impl!r}")
+    return impl
+
+
+def stage_a_bd(d: int, k: int) -> Optional[int]:
+    """D-tile width stage A needs for ``d`` items, or None (monolithic).
+
+    The monolithic kernel holds [bq, D] and [bm, D] blocks; once its
+    block model no longer fits VMEM (``analysis.vmem.fits_vmem``) the
+    D-tiled kernel (VMEM flat in D, DESIGN.md §8.4) takes over — the
+    chip's compiler refuses the monolithic kernel from D = 2560 on, far
+    below the paper's 12k-item catalogues.  Chosen from the
+    shape alone, so every impl serves the same regime.
+    """
+    if _analysis_vmem.fits_vmem(_analysis_vmem.knn_topk_block_bytes(d=d,
+                                                                   k=k)):
+        return None
+    return 512
 
 
 def knn_topk(queries: jax.Array, corpus: jax.Array, k: int,
@@ -75,13 +113,11 @@ def knn_topk(queries: jax.Array, corpus: jax.Array, k: int,
     merge — never a [Q, M] score matrix in HBM (DESIGN.md §3.4).
     impl: auto | pallas | interpret | ref.
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    impl = resolve_impl(impl)
+    if impl == "ref":
         return ref.knn_topk_ref(queries, corpus, k,
                                 kw.get("metric", "euclidean"))
-    return _knn_pallas(queries, corpus, k,
-                       interpret=(impl == "interpret" or not _on_tpu()),
-                       **kw)
+    return _knn_pallas(queries, corpus, k, interpret=impl == "interpret", **kw)
 
 
 def knn_topk_dtiled(queries: jax.Array, corpus: jax.Array, k: int,
@@ -95,77 +131,88 @@ def knn_topk_dtiled(queries: jax.Array, corpus: jax.Array, k: int,
     .quantize_int8_rows`) and are bitwise the `ref.dtiled_topk_ref`
     oracle on every impl.  impl: auto | pallas | interpret | ref.
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    impl = resolve_impl(impl)
+    if impl == "ref":
         return ref.dtiled_topk_ref(queries, corpus, k, bd=bd, **kw)
     return _knn_dtiled_pallas(queries, corpus, k, bd=bd,
-                              interpret=(impl == "interpret"
-                                         or not _on_tpu()), **kw)
+                              interpret=impl == "interpret", **kw)
 
 
 # ---------------------------------------------------------------------------
 # Fused serving pipeline (DESIGN.md §8)
 # ---------------------------------------------------------------------------
+#
+# ``n_items`` everywhere below is the number of REAL item columns: the
+# state store pads the item axis to the lane tile (DESIGN.md §3.3), and
+# columns at or past ``n_items`` are never recommended.  None means
+# every column is real.
 
-@functools.partial(jax.jit, static_argnames=("k", "topn", "metric"))
+@functools.partial(jax.jit, static_argnames=("k", "topn", "metric",
+                                             "n_items"))
 def _fused_recommend_ref(corpus: jax.Array, user_ids: jax.Array,
-                         alpha: float, k: int, topn: int,
-                         metric: str) -> jax.Array:
-    return ref.fused_recommend_ref(corpus, user_ids, k, alpha, topn, metric)
+                         alpha: float, k: int, topn: int, metric: str,
+                         n_items: Optional[int]) -> jax.Array:
+    return ref.fused_recommend_ref(corpus, user_ids, k, alpha, topn, metric,
+                                   n_items=n_items)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "alpha", "topn", "metric",
-                                    "interpret"))
+                                    "n_items", "interpret"))
 def _fused_recommend_pallas(corpus: jax.Array, user_ids: jax.Array,
                             k: int, alpha: float, topn: int, metric: str,
+                            n_items: Optional[int],
                             interpret: bool) -> jax.Array:
     queries = corpus[user_ids]
     _, idx = _knn_pallas(queries, corpus, k, metric=metric,
                          query_gids=user_ids, interpret=interpret)
     _, ids = _blend_onehot(corpus, user_ids, idx, alpha=alpha, topn=topn,
-                           interpret=interpret)
+                           n_items=n_items, interpret=interpret)
     return ids
 
 
-@functools.partial(jax.jit, static_argnames=("k", "topn", "bd"))
+@functools.partial(jax.jit, static_argnames=("k", "topn", "bd", "n_items"))
 def _fused_recommend_dtiled_ref(corpus: jax.Array, user_ids: jax.Array,
-                                alpha: float, k: int, topn: int,
-                                bd: int) -> jax.Array:
+                                alpha: float, k: int, topn: int, bd: int,
+                                n_items: Optional[int]) -> jax.Array:
     queries = corpus[user_ids]
     _, idx = ref.dtiled_topk_ref(queries, corpus, k, bd=bd,
                                  query_gids=user_ids)
-    return ref.blend_topn_rows_ref(queries, corpus[idx], alpha, topn)
+    return ref.blend_topn_rows_ref(queries, corpus[idx], alpha, topn,
+                                   n_items=n_items)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "alpha", "topn", "bd",
+                   static_argnames=("k", "alpha", "topn", "bd", "n_items",
                                     "interpret"))
 def _fused_recommend_dtiled_pallas(corpus: jax.Array, user_ids: jax.Array,
                                    k: int, alpha: float, topn: int,
-                                   bd: int, interpret: bool) -> jax.Array:
+                                   bd: int, n_items: Optional[int],
+                                   interpret: bool) -> jax.Array:
     queries = corpus[user_ids]
     _, idx = _knn_dtiled_pallas(queries, corpus, k, bd=bd,
                                 query_gids=user_ids, interpret=interpret)
     _, ids = _blend_onehot(corpus, user_ids, idx, alpha=alpha, topn=topn,
-                           interpret=interpret)
+                           n_items=n_items, interpret=interpret)
     return ids
 
 
-@functools.partial(jax.jit, static_argnames=("k", "topn", "bd"))
+@functools.partial(jax.jit, static_argnames=("k", "topn", "bd", "n_items"))
 def _fused_recommend_quant_ref(corpus_q: jax.Array, c_scale: jax.Array,
                                user_ids: jax.Array, alpha: float, k: int,
-                               topn: int, bd: int) -> jax.Array:
+                               topn: int, bd: int,
+                               n_items: Optional[int]) -> jax.Array:
     return ref.fused_recommend_quant_ref(corpus_q, c_scale, user_ids, k,
-                                         alpha, topn, bd)
+                                         alpha, topn, bd, n_items=n_items)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "alpha", "topn", "bd",
+                   static_argnames=("k", "alpha", "topn", "bd", "n_items",
                                     "interpret"))
 def _fused_recommend_quant_pallas(corpus_q: jax.Array, c_scale: jax.Array,
                                   user_ids: jax.Array, k: int,
                                   alpha: float, topn: int, bd: int,
+                                  n_items: Optional[int],
                                   interpret: bool) -> jax.Array:
     queries_q = corpus_q[user_ids]
     q_scale = c_scale[user_ids]
@@ -176,33 +223,39 @@ def _fused_recommend_quant_pallas(corpus_q: jax.Array, c_scale: jax.Array,
     # ¼ the HBM bytes of the fp32 gather (DESIGN.md §8.4)
     _, ids = _blend_rows_quant_pallas(queries_q, q_scale, corpus_q[idx],
                                       c_scale[idx], alpha=alpha,
-                                      topn=topn, interpret=interpret)
+                                      topn=topn, n_items=n_items,
+                                      interpret=interpret)
     return ids
+
+
+def _check_topn(topn: int, n_cols: int, n_items: Optional[int]) -> None:
+    n_real = n_cols if n_items is None else n_items
+    if topn > n_real:
+        raise ValueError(f"topn={topn} > n_items={n_real}")
 
 
 def fused_recommend(corpus: jax.Array, user_ids: jax.Array, k: int,
                     alpha: float, topn: int, metric: str = "euclidean",
-                    impl: str | None = None,
-                    bd: int | None = None) -> jax.Array:
+                    impl: str | None = None, bd: int | None = None,
+                    n_items: int | None = None) -> jax.Array:
     """Fused serving path: corpus rows → top-n item ids, one program.
 
     ``corpus`` f32[M, I] (the cached serving corpus), ``user_ids``
     i32[Q] corpus rows (self-excluded from their own neighbourhood) →
     i32[Q, topn].  The TPU path is the two-stage Pallas pipeline of
-    DESIGN.md §8 (streaming top-k + one-hot blend/top-n: O(Q·k) HBM
-    intermediates); the CPU path is the XLA reference — bitwise the
-    historical `recommend_for_users` output.  ``k`` is clamped to M−1
-    (see the comment at the clamp); cosine falls back to the reference
-    (the kernels fuse the euclidean surrogate / dot only).
-    ``bd`` (optional, euclidean only) routes stage A through the
-    D-tiled kernel of DESIGN.md §8.4 — same results, VMEM flat in the
-    item count; required beyond the monolithic kernel's ~64k-item wall.
-    impl: auto | pallas | interpret | ref.
+    DESIGN.md §8 (streaming top-k + one-hot blend/top-n); the CPU path
+    is the XLA reference of the same regime.  ``k`` is clamped to M−1
+    (see the comment at the clamp); cosine serves through the reference
+    (the kernels fuse the euclidean surrogate / dot only).  Stage A runs
+    the D-tiled kernel of DESIGN.md §8.4 with tile width ``bd`` when
+    given, else when the monolithic kernel would not fit VMEM
+    (:func:`stage_a_bd`).  ``n_items`` (real item columns, see above)
+    masks the store's pad columns.  impl: auto | pallas | interpret |
+    ref.
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     q_n, m = user_ids.shape[0], corpus.shape[0]
-    if topn > corpus.shape[1]:
-        raise ValueError(f"topn={topn} > n_items={corpus.shape[1]}")
+    _check_topn(topn, corpus.shape[1], n_items)
     if q_n == 0 or m == 0:
         return jnp.zeros((q_n, topn), jnp.int32)
     # clamp BELOW m: self-exclusion leaves m−1 finite candidates, and a
@@ -210,26 +263,32 @@ def fused_recommend(corpus: jax.Array, user_ids: jax.Array, k: int,
     # (accumulator-init index) than in the reference (the self row) —
     # keeping every selected candidate finite keeps the paths identical
     k = max(1, min(k, m - 1))
-    if impl == "ref" or metric == "cosine" \
-            or (impl == "auto" and not _on_tpu()):
-        if bd is not None and metric != "cosine":
-            return _fused_recommend_dtiled_ref(corpus, user_ids, alpha,
-                                               k=k, topn=topn, bd=bd)
-        return _fused_recommend_ref(corpus, user_ids, alpha, k=k,
-                                    topn=topn, metric=metric)
+    if metric == "cosine":
+        return _fused_recommend_ref(corpus, user_ids, alpha, k=k, topn=topn,
+                                    metric=metric, n_items=n_items)
+    if bd is None and metric == "euclidean":
+        bd = stage_a_bd(corpus.shape[1], k)
+    if impl == "ref":
+        if bd is not None:
+            return _fused_recommend_dtiled_ref(corpus, user_ids, alpha, k=k,
+                                               topn=topn, bd=bd,
+                                               n_items=n_items)
+        return _fused_recommend_ref(corpus, user_ids, alpha, k=k, topn=topn,
+                                    metric=metric, n_items=n_items)
     if bd is not None:
         return _fused_recommend_dtiled_pallas(
             corpus, user_ids, k=k, alpha=float(alpha), topn=topn, bd=bd,
-            interpret=(impl == "interpret" or not _on_tpu()))
+            n_items=n_items, interpret=impl == "interpret")
     return _fused_recommend_pallas(
         corpus, user_ids, k=k, alpha=float(alpha), topn=topn,
-        metric=metric, interpret=(impl == "interpret" or not _on_tpu()))
+        metric=metric, n_items=n_items, interpret=impl == "interpret")
 
 
 def fused_recommend_quant(corpus_q: jax.Array, c_scale: jax.Array,
                           user_ids: jax.Array, k: int,
                           alpha: float, topn: int, bd: int = 512,
-                          impl: str | None = None) -> jax.Array:
+                          impl: str | None = None,
+                          n_items: int | None = None) -> jax.Array:
     """Int8 fused serving (DESIGN.md §8.4): quantized corpus → top-n ids.
 
     ``corpus_q`` int8[M, I] with per-row ``c_scale`` f32[M]
@@ -240,44 +299,57 @@ def fused_recommend_quant(corpus_q: jax.Array, c_scale: jax.Array,
     impl); stage B gathers only the selected k rows, int8 on the wire,
     and dequantizes in VMEM.  HBM traffic per query batch is
     O(Q/bq · M·I) int8 reads + O(Q·k·I) int8 + O(Q·n) out — ¼ the
-    fp32 path's bytes.  Euclidean only.  impl: auto | pallas |
-    interpret | ref.
+    fp32 path's bytes.  Euclidean only.  ``n_items`` as in
+    :func:`fused_recommend`.  impl: auto | pallas | interpret | ref.
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     q_n, m = user_ids.shape[0], corpus_q.shape[0]
-    if topn > corpus_q.shape[1]:
-        raise ValueError(f"topn={topn} > n_items={corpus_q.shape[1]}")
+    _check_topn(topn, corpus_q.shape[1], n_items)
     if q_n == 0 or m == 0:
         return jnp.zeros((q_n, topn), jnp.int32)
     k = max(1, min(k, m - 1))   # same −inf-slot reasoning as above
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    if impl == "ref":
         return _fused_recommend_quant_ref(corpus_q, c_scale, user_ids,
-                                          alpha, k=k, topn=topn, bd=bd)
+                                          alpha, k=k, topn=topn, bd=bd,
+                                          n_items=n_items)
     return _fused_recommend_quant_pallas(
         corpus_q, c_scale, user_ids, k=k, alpha=float(alpha), topn=topn,
-        bd=bd, interpret=(impl == "interpret" or not _on_tpu()))
+        bd=bd, n_items=n_items, interpret=impl == "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=("k", "shard", "n_shards",
-                                             "metric"))
+                                             "metric", "bd"))
 def _shard_topk_ref(queries: jax.Array, corpus: jax.Array,
-                    query_gids: Optional[jax.Array], k: int, shard: int,
-                    n_shards: int,
-                    metric: str) -> Tuple[jax.Array, jax.Array]:
-    return ref.shard_topk_ref(queries, corpus, k, shard, n_shards,
-                              query_gids, metric)
+                    query_gids: jax.Array, k: int, shard: int,
+                    n_shards: int, metric: str,
+                    bd: Optional[int]) -> Tuple[jax.Array, jax.Array]:
+    if bd is None:
+        return ref.shard_topk_ref(queries, corpus, k, shard, n_shards,
+                                  query_gids, metric)
+    vals, idx = ref.dtiled_topk_ref(queries, corpus, k, bd=bd,
+                                    query_gids=query_gids,
+                                    col_offset=shard, col_stride=n_shards,
+                                    sub_qnorm=True)
+    gids = idx * n_shards + shard
+    return vals, jnp.where(jnp.isneginf(vals), query_gids[:, None], gids)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "shard", "n_shards",
-                                             "metric", "interpret"))
+                                             "metric", "bd", "interpret"))
 def _shard_topk_pallas(queries: jax.Array, corpus: jax.Array,
                        query_gids: jax.Array, k: int, shard: int,
-                       n_shards: int, metric: str,
+                       n_shards: int, metric: str, bd: Optional[int],
                        interpret: bool) -> Tuple[jax.Array, jax.Array]:
-    vals, idx = _knn_pallas(queries, corpus, k, metric=metric,
-                            query_gids=query_gids, col_offset=shard,
-                            col_stride=n_shards, sub_qnorm=True,
-                            interpret=interpret)
+    if bd is None:
+        vals, idx = _knn_pallas(queries, corpus, k, metric=metric,
+                                query_gids=query_gids, col_offset=shard,
+                                col_stride=n_shards, sub_qnorm=True,
+                                interpret=interpret)
+    else:
+        vals, idx = _knn_dtiled_pallas(queries, corpus, k, bd=bd,
+                                       query_gids=query_gids,
+                                       col_offset=shard, col_stride=n_shards,
+                                       sub_qnorm=True, interpret=interpret)
     gids = idx * n_shards + shard
     # k >= m_s on the owner shard admits the excluded self column as a
     # −inf candidate; the reference resolves its index to the self row
@@ -296,27 +368,29 @@ def shard_topk(queries: jax.Array, corpus: jax.Array, k: int, shard: int,
     ``k' = min(k, M_s)``.  The TPU path streams corpus tiles through the
     fused top-k kernel with the shard's global-id mapping (column gid =
     ``row·n_shards + shard``) — the [Q, M_s] score matrix never reaches
-    HBM; the CPU path is bitwise the historical
-    `shard_topk_candidates`.  Cosine falls back to the reference.
+    HBM; the CPU path is the XLA reference of the same regime.  Stage A
+    is D-tiled where the monolithic kernel would not fit VMEM
+    (:func:`stage_a_bd`).  Cosine serves through the reference.
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     m_s = corpus.shape[0]
     q_n = queries.shape[0]
     if m_s == 0 or q_n == 0:
         kk = min(k, m_s)
         return (jnp.full((q_n, kk), -jnp.inf, jnp.float32),
                 jnp.zeros((q_n, kk), jnp.int32))
-    if impl == "ref" or metric == "cosine" \
-            or (impl == "auto" and not _on_tpu()):
-        return _shard_topk_ref(queries, corpus, query_gids, k=k,
+    if query_gids is None:
+        query_gids = jnp.full((q_n,), -1, jnp.int32)
+    bd = (stage_a_bd(corpus.shape[1], min(k, m_s))
+          if metric == "euclidean" else None)
+    if impl == "ref" or metric == "cosine":
+        return _shard_topk_ref(queries, corpus, query_gids, k=min(k, m_s),
                                shard=shard, n_shards=n_shards,
-                               metric=metric)
+                               metric=metric, bd=bd)
     return _shard_topk_pallas(
-        queries, corpus,
-        (query_gids if query_gids is not None
-         else jnp.full((q_n,), -1, jnp.int32)),
-        k=min(k, m_s), shard=shard, n_shards=n_shards, metric=metric,
-        interpret=(impl == "interpret" or not _on_tpu()))
+        queries, corpus, query_gids, k=min(k, m_s), shard=shard,
+        n_shards=n_shards, metric=metric, bd=bd,
+        interpret=impl == "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=("k", "shard", "n_shards",
@@ -370,7 +444,7 @@ def shard_topk_quant(queries_q: jax.Array, q_scale: jax.Array,
     bitwise-consistent (DESIGN.md §7.3/§8.4).  Bitwise the oracle on
     every impl.  impl: auto | pallas | interpret | ref.
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     m_s = corpus_q.shape[0]
     q_n = queries_q.shape[0]
     if m_s == 0 or q_n == 0:
@@ -379,83 +453,92 @@ def shard_topk_quant(queries_q: jax.Array, q_scale: jax.Array,
                 jnp.zeros((q_n, kk), jnp.int32))
     if query_gids is None:
         query_gids = jnp.full((q_n,), -1, jnp.int32)
-    fn = (_shard_topk_quant_ref
-          if impl == "ref" or (impl == "auto" and not _on_tpu())
-          else functools.partial(
-              _shard_topk_quant_pallas,
-              interpret=(impl == "interpret" or not _on_tpu())))
+    fn = (_shard_topk_quant_ref if impl == "ref"
+          else functools.partial(_shard_topk_quant_pallas,
+                                 interpret=impl == "interpret"))
     return fn(queries_q, q_scale, corpus_q, c_scale, query_gids,
               k=min(k, m_s), shard=shard, n_shards=n_shards, bd=bd)
 
 
-@functools.partial(jax.jit, static_argnames=("topn",))
+@functools.partial(jax.jit, static_argnames=("topn", "n_items"))
 def _blend_rows_ref(queries: jax.Array, neighbor_rows: jax.Array,
-                    alpha: float, topn: int) -> jax.Array:
-    return ref.blend_topn_rows_ref(queries, neighbor_rows, alpha, topn)
+                    alpha: float, topn: int,
+                    n_items: Optional[int]) -> jax.Array:
+    return ref.blend_topn_rows_ref(queries, neighbor_rows, alpha, topn,
+                                   n_items=n_items)
 
 
-@functools.partial(jax.jit, static_argnames=("alpha", "topn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("alpha", "topn", "n_items",
+                                             "interpret"))
 def _blend_rows_pallas(queries: jax.Array, neighbor_rows: jax.Array,
-                       alpha: float, topn: int,
+                       alpha: float, topn: int, n_items: Optional[int],
                        interpret: bool) -> jax.Array:
     return _blend_rows(queries, neighbor_rows, alpha=alpha, topn=topn,
-                       interpret=interpret)[1]
+                       n_items=n_items, interpret=interpret)[1]
 
 
 def blend_topn_rows(queries: jax.Array, neighbor_rows: jax.Array,
-                    alpha: float, topn: int,
-                    impl: str | None = None) -> jax.Array:
+                    alpha: float, topn: int, impl: str | None = None,
+                    n_items: int | None = None) -> jax.Array:
     """Cross-shard final stage: fetched rows [Q, k, I] → top-n ids.
 
     Mean over k + alpha blend + top-n; the TPU path fuses them per item
-    tile (no [Q, I] prediction intermediate), the CPU path is bitwise
-    the historical ``_combine_neighbors``.
+    tile (no [Q, I] prediction intermediate), the CPU path is the
+    historical ``_combine_neighbors`` math.  ``n_items`` as in
+    :func:`fused_recommend`.
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
-        return _blend_rows_ref(queries, neighbor_rows, alpha, topn=topn)
-    return _blend_rows_pallas(
-        queries, neighbor_rows, alpha=float(alpha), topn=topn,
-        interpret=(impl == "interpret" or not _on_tpu()))
+    impl = resolve_impl(impl)
+    _check_topn(topn, queries.shape[1], n_items)
+    if impl == "ref":
+        return _blend_rows_ref(queries, neighbor_rows, alpha, topn=topn,
+                               n_items=n_items)
+    return _blend_rows_pallas(queries, neighbor_rows, alpha=float(alpha),
+                              topn=topn, n_items=n_items,
+                              interpret=impl == "interpret")
 
 
-@functools.partial(jax.jit, static_argnames=("topn",))
+@functools.partial(jax.jit, static_argnames=("topn", "n_items"))
 def _blend_rows_quant_ref(queries_q: jax.Array, q_scale: jax.Array,
                           neighbor_rows_q: jax.Array, n_scale: jax.Array,
-                          alpha: float, topn: int) -> jax.Array:
+                          alpha: float, topn: int,
+                          n_items: Optional[int]) -> jax.Array:
     return ref.blend_topn_rows_quant_ref(queries_q, q_scale,
                                          neighbor_rows_q, n_scale, alpha,
-                                         topn)
+                                         topn, n_items=n_items)
 
 
-@functools.partial(jax.jit, static_argnames=("alpha", "topn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("alpha", "topn", "n_items",
+                                             "interpret"))
 def _blend_rows_quant_pallas_ids(queries_q: jax.Array, q_scale: jax.Array,
                                  neighbor_rows_q: jax.Array,
                                  n_scale: jax.Array, alpha: float,
-                                 topn: int,
+                                 topn: int, n_items: Optional[int],
                                  interpret: bool) -> jax.Array:
     return _blend_rows_quant_pallas(queries_q, q_scale, neighbor_rows_q,
                                     n_scale, alpha=alpha, topn=topn,
-                                    interpret=interpret)[1]
+                                    n_items=n_items, interpret=interpret)[1]
 
 
 def blend_topn_rows_quant(queries_q: jax.Array, q_scale: jax.Array,
                           neighbor_rows_q: jax.Array, n_scale: jax.Array,
                           alpha: float, topn: int,
-                          impl: str | None = None) -> jax.Array:
+                          impl: str | None = None,
+                          n_items: int | None = None) -> jax.Array:
     """Quantized cross-shard final stage: int8 rows [Q, k, I] → top-n.
 
     The int8 twin of :func:`blend_topn_rows`: the k fetched rows cross
     the wire quantized (¼ the fp32 bytes) with per-row scales and are
     dequantized on-chip.  impl: auto | pallas | interpret | ref.
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    impl = resolve_impl(impl)
+    _check_topn(topn, queries_q.shape[1], n_items)
+    if impl == "ref":
         return _blend_rows_quant_ref(queries_q, q_scale, neighbor_rows_q,
-                                     n_scale, alpha, topn=topn)
+                                     n_scale, alpha, topn=topn,
+                                     n_items=n_items)
     return _blend_rows_quant_pallas_ids(
         queries_q, q_scale, neighbor_rows_q, n_scale, alpha=float(alpha),
-        topn=topn, interpret=(impl == "interpret" or not _on_tpu()))
+        topn=topn, n_items=n_items, interpret=impl == "interpret")
 
 
 def serving_cache_size() -> int:
@@ -500,24 +583,23 @@ def multihot_scatter(ids: jax.Array, weights: jax.Array, n_items: int,
     One decayed-average user/group vector per call: O(N·B) input ids
     against an [n_items] output (DESIGN.md §3.1).
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    impl = resolve_impl(impl)
+    if impl == "ref":
         return ref.decayed_scatter_ref(ids, weights, n_items)
     if ids.ndim == 3:
         return batched_decayed_scatter(ids, weights, n_items,
-                                       interpret=(impl == "interpret"
-                                                  or not _on_tpu()))
-    return decayed_scatter(ids, weights, n_items,
-                           interpret=(impl == "interpret" or not _on_tpu()))
+                                       interpret=impl == "interpret")
+    return decayed_scatter(ids, weights, n_items, interpret=impl == "interpret")
 
 
 def plan_bi(n_items: int) -> int | None:
     """Item-tile width for the tile-planned kernels, or None.
 
     The largest lane-aligned tile (512/256/128) dividing ``n_items``;
-    None means the Pallas path falls back to the XLA reference.  Public
-    so hint producers (the streaming engine's host-measured ``T_max``,
-    DESIGN.md §3.3) bucket ids with the same tile width the kernels use.
+    None means the kernels cannot run at this width (the state store
+    pads its item axis so they always can).  Public so hint producers
+    (the streaming engine's host-measured ``T_max``, DESIGN.md §3.3)
+    bucket ids with the same tile width the kernels use.
     """
     for bi in (512, 256, 128):
         if n_items % bi == 0:
@@ -526,10 +608,12 @@ def plan_bi(n_items: int) -> int | None:
 
 
 def _plan_dims(n_items: int, ids: jax.Array,
-               t_max_cap: int = 0) -> Tuple[int, int] | None:
-    """(bi, t_max) for the tile-planned kernels, or None → ref fallback.
+               t_max_cap: int = 0) -> Tuple[int, int]:
+    """(bi, t_max) for the tile-planned kernels.
 
-    ``bi`` is the largest lane-aligned tile dividing ``n_items``;
+    ``bi`` is the largest lane-aligned tile dividing ``n_items`` (an
+    untileable width raises: the state store pads its item axis, so
+    reaching one is a bug, not a reason to switch implementation);
     ``t_max`` is the static per-row touched-tile bound.  When ``ids`` is
     concrete (benchmark / direct calls outside jit) the true maximum is
     measured on host and pow2-bucketed — typical baskets touch only a
@@ -541,7 +625,10 @@ def _plan_dims(n_items: int, ids: jax.Array,
     """
     bi = plan_bi(n_items)
     if bi is None:
-        return None
+        raise ValueError(
+            f"the tile-planned kernels need an item width divisible by 128, "
+            f"got {n_items}; StateStore pads its item axis to the lane tile "
+            "(StoreConfig.n_cols)")
     w = ids.shape[1]
     cap = max(1, min(w, n_items // bi))
     if isinstance(ids, jax.core.Tracer):
@@ -562,16 +649,12 @@ def sparse_row_scatter(table: jax.Array, rows: jax.Array, ids: jax.Array,
     tiles that shrinks the kernel grid under jit; it MUST be sound (>=
     the true maximum) — the plan truncates beyond it.
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    impl = resolve_impl(impl)
+    if impl == "ref":
         return ref.sparse_row_scatter_ref(table, rows, ids, vals)
-    dims = _plan_dims(table.shape[1], ids, t_max_cap)
-    if dims is None:
-        return ref.sparse_row_scatter_ref(table, rows, ids, vals)
-    bi, t_max = dims
-    return _sparse_scatter_pallas(
-        table, rows, ids, vals, bi=bi, t_max=t_max,
-        interpret=(impl == "interpret" or not _on_tpu()))
+    bi, t_max = _plan_dims(table.shape[1], ids, t_max_cap)
+    return _sparse_scatter_pallas(table, rows, ids, vals, bi=bi, t_max=t_max,
+                                  interpret=impl == "interpret")
 
 
 def sparse_row_gather(table: jax.Array, rows: jax.Array, ids: jax.Array,
@@ -584,16 +667,12 @@ def sparse_row_gather(table: jax.Array, rows: jax.Array, ids: jax.Array,
     tiles — O(U·W) HBM traffic too).  ``t_max_cap`` as in
     :func:`sparse_row_scatter`.
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    impl = resolve_impl(impl)
+    if impl == "ref":
         return ref.sparse_row_gather_ref(table, rows, ids)
-    dims = _plan_dims(table.shape[1], ids, t_max_cap)
-    if dims is None:
-        return ref.sparse_row_gather_ref(table, rows, ids)
-    bi, t_max = dims
-    return _sparse_gather_pallas(
-        table, rows, ids, bi=bi, t_max=t_max,
-        interpret=(impl == "interpret" or not _on_tpu()))
+    bi, t_max = _plan_dims(table.shape[1], ids, t_max_cap)
+    return _sparse_gather_pallas(table, rows, ids, bi=bi, t_max=t_max,
+                                 interpret=impl == "interpret")
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -604,9 +683,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     O(S²·D) compute with O(S·D) memory (never an [S, S] score matrix in
     HBM); serves the LM stack, not the TIFU maintenance path.
     """
-    impl = _resolve(impl)
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+    impl = resolve_impl(impl)
+    if impl == "ref":
         return ref.flash_attention_ref(q, k, v, causal, window)
     return _flash_pallas(q, k, v, causal=causal, window=window,
-                        interpret=(impl == "interpret" or not _on_tpu()),
-                        **kw)
+                        interpret=impl == "interpret", **kw)

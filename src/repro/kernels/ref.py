@@ -45,14 +45,15 @@ def _pairwise_scores(queries, corpus, metric: str):
 
 
 def fused_recommend_ref(corpus, user_ids, k: int, alpha, topn: int,
-                        metric: str = "euclidean"):
+                        metric: str = "euclidean", n_items=None):
     """Oracle for the fused serving pipeline (DESIGN.md §8).
 
     Computes EXACTLY what the pre-fusion `core.knn.recommend_for_users`
     computed — row gather, full-score nearest neighbours with self
     exclusion, [Q, k, I] neighbour gather + mean, alpha blend, top-n —
     in the same operation order, so the dispatcher's CPU path stays
-    bitwise-identical to the historical serving output.
+    bitwise-identical to the historical serving output.  Only the first
+    ``n_items`` columns (None: all) are candidates for the top-n.
     """
     queries = corpus[user_ids]
     scores = _pairwise_scores(queries, corpus, metric)
@@ -60,7 +61,7 @@ def fused_recommend_ref(corpus, user_ids, k: int, alpha, topn: int,
     _, idx = jax.lax.top_k(scores, k)
     neighbors = jnp.mean(corpus[idx], axis=1)
     pred = alpha * queries + (1.0 - alpha) * neighbors
-    return jax.lax.top_k(pred, topn)[1]
+    return jax.lax.top_k(pred[:, :n_items], topn)[1]
 
 
 def shard_topk_ref(queries, corpus, k: int, shard: int, n_shards: int,
@@ -83,12 +84,14 @@ def shard_topk_ref(queries, corpus, k: int, shard: int, n_shards: int,
     return vals, col_gid[idx]
 
 
-def blend_topn_rows_ref(queries, neighbor_rows, alpha, topn: int):
+def blend_topn_rows_ref(queries, neighbor_rows, alpha, topn: int,
+                        n_items=None):
     """Oracle for the cross-shard blend: mean over the fetched rows,
-    alpha blend, top-n — the pre-fusion ``_combine_neighbors`` math."""
+    alpha blend, top-n over the first ``n_items`` columns (None: all) —
+    the pre-fusion ``_combine_neighbors`` math."""
     neighbors = jnp.mean(neighbor_rows, axis=1)
     pred = alpha * queries + (1.0 - alpha) * neighbors
-    return jax.lax.top_k(pred, topn)[1]
+    return jax.lax.top_k(pred[:, :n_items], topn)[1]
 
 
 def tiled_sqnorm_ref(x, bd: int):
@@ -175,7 +178,7 @@ def dtiled_topk_ref(queries, corpus, k: int, bd: int = 512,
 
 
 def blend_topn_rows_quant_ref(queries_q, q_scale, neighbor_rows_q,
-                              n_scale, alpha, topn: int):
+                              n_scale, alpha, topn: int, n_items=None):
     """Oracle for the quantized cross-shard blend (stage B, §8.4).
 
     queries_q int8[Q, I] / q_scale f32[Q]; neighbor_rows_q
@@ -187,11 +190,11 @@ def blend_topn_rows_quant_ref(queries_q, q_scale, neighbor_rows_q,
     nbr = neighbor_rows_q.astype(jnp.float32) * n_scale[:, :, None]
     neighbors = jnp.mean(nbr, axis=1)
     pred = alpha * queries + (1.0 - alpha) * neighbors
-    return jax.lax.top_k(pred, topn)[1]
+    return jax.lax.top_k(pred[:, :n_items], topn)[1]
 
 
 def fused_recommend_quant_ref(corpus_q, c_scale, user_ids, k: int, alpha,
-                              topn: int, bd: int = 512):
+                              topn: int, bd: int = 512, n_items=None):
     """Oracle for the int8 fused serving pipeline (DESIGN.md §8.4).
 
     The query IS the user's quantized corpus row (q_scale =
@@ -206,7 +209,8 @@ def fused_recommend_quant_ref(corpus_q, c_scale, user_ids, k: int, alpha,
                              query_gids=user_ids, q_scale=q_scale,
                              c_scale=c_scale)
     return blend_topn_rows_quant_ref(queries_q, q_scale, corpus_q[idx],
-                                     c_scale[idx], alpha, topn)
+                                     c_scale[idx], alpha, topn,
+                                     n_items=n_items)
 
 
 def decayed_scatter_ref(ids, weights, n_items: int):

@@ -14,20 +14,25 @@ item tile's iota, contracted with vals.
 The grid is driven by a **touched-tile plan** (kernels.tile_plan): the
 ``(U, T_max)`` step sequence enumerates only the ``(target row, item
 tile)`` blocks some row's ids actually touch, sorted by (row, tile) so
-every output block's visits — including visits contributed by duplicate
-target rows — are *consecutive* grid steps.  The scalar-prefetched plan
-arrays drive the block index maps; a step DMAs only a genuinely dirty
-``[1, bi]`` tile (padding steps clone the previous block, which the
-pipeline does not re-fetch, and a PAD ``pl.when`` guard skips their
-compute), so HBM traffic is O(U·W) — matching the XLA reference path's
-asymptotics (kernels.ref.sparse_row_scatter_ref, the CPU/GPU path) and
-the paper's flat latency-vs-vocabulary curve on TPU.
+every block's visits — including visits contributed by duplicate
+target rows — are *consecutive* grid steps.  The table stays in HBM
+(``memory_space=pl.ANY``, aliased in place): the first step of a run
+DMAs the ``[8, bi]`` row group holding the target row into a VMEM
+accumulator (HBM slices must be (8, 128)-aligned), every valid step adds
+its row's contribution to that row's sublane, and the run's last step
+DMAs the group back.  Padding steps clone the previous work item and
+are ``pl.when``-guarded out of the compute, so HBM traffic is O(U·W) —
+matching the XLA reference path's asymptotics
+(kernels.ref.sparse_row_scatter_ref, the CPU/GPU path) and the paper's
+flat latency-vs-vocabulary curve.
 
-Within one output block's run the kernel accumulates in a VMEM scratch
-(loaded on the run's first step, stored on its last), which is the same
-consecutive-revisit contract the pre-plan kernel relied on — the plan's
-(row, tile) sort is what makes it hold for duplicate rows with differing
-supports.
+``ids``/``vals`` are whole-array VMEM blocks (only ``[U, W]``); a step
+reads its batch row ``plan.batch[s]`` from them.  Per step the update
+is one MXU contraction ``vals[1, W] · onehot[bi, W]ᵀ`` against the
+item tile's iota, at HIGHEST precision so each one-hot product is the
+exact f32 value.  The DMAs are synchronous, so a later run on a
+neighbouring row of the same 8-row group always reads what an earlier
+run wrote.
 """
 from __future__ import annotations
 
@@ -44,8 +49,8 @@ from repro.kernels.tile_plan import build_plan
 
 
 def _kernel(pbatch_ref, prow_ref, ptile_ref, pvalid_ref, ids_ref, vals_ref,
-            tab_ref, out_ref, acc, *, bi: int, t_max: int):
-    del pbatch_ref  # consumed by the ids/vals index maps only
+            tab_hbm, out_hbm, acc, sem, *, bi: int, t_max: int):
+    del tab_hbm  # aliased to out_hbm: every read and write goes there
     r = pl.program_id(0)
     t = pl.program_id(1)
     s = r * t_max + t
@@ -57,24 +62,33 @@ def _kernel(pbatch_ref, prow_ref, ptile_ref, pvalid_ref, ids_ref, vals_ref,
     sn = jnp.minimum(s + 1, ns - 1)
     prev_same = (s > 0) & (prow_ref[sp] == row) & (ptile_ref[sp] == tile)
     next_same = (s < ns - 1) & (prow_ref[sn] == row) & (ptile_ref[sn] == tile)
+    # HBM slices must be (8, 128)-aligned: move the 8-row group holding
+    # ``row`` and work on its sublane ``row % 8``
+    block = out_hbm.at[pl.ds(pl.multiple_of(row // 8 * 8, 8), 8),
+                       pl.ds(pl.multiple_of(tile * bi, bi), bi)]
 
     @pl.when(jnp.logical_not(prev_same))
     def _load():
-        acc[...] = tab_ref[0, :]
+        pltpu.make_async_copy(block, acc, sem).start()
+        pltpu.make_async_copy(block, acc, sem).wait()
 
     @pl.when(pvalid_ref[s] == 1)
     def _accumulate():
-        ids = ids_ref[0, :]                          # [W] i32, PAD=-1
-        vals = vals_ref[0, :]                        # [W] f32
-        base = tile * bi
-        grid = base + jax.lax.broadcasted_iota(jnp.int32,
-                                               (ids.shape[0], bi), 1)
-        onehot = (ids[:, None] == grid).astype(jnp.float32)  # PAD misses
-        acc[...] += jnp.sum(onehot * vals[:, None], axis=0)
+        b = pbatch_ref[s]
+        ids = ids_ref[pl.ds(b, 1), :]                # [1, W] i32, PAD=-1
+        vals = vals_ref[pl.ds(b, 1), :]              # [1, W] f32
+        items = tile * bi + jax.lax.broadcasted_iota(
+            jnp.int32, (bi, ids.shape[1]), 0)
+        onehot = (items == ids).astype(jnp.float32)  # [bi, W]; PAD misses
+        acc[pl.ds(row % 8, 1), :] += jax.lax.dot_general(
+            vals, onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)      # [1, bi]
 
     @pl.when(jnp.logical_not(next_same))
     def _store():
-        out_ref[0, :] = acc[...]
+        pltpu.make_async_copy(acc, block, sem).start()
+        pltpu.make_async_copy(acc, block, sem).wait()
 
 
 @functools.partial(jax.jit, static_argnames=("bi", "t_max", "interpret"))
@@ -84,11 +98,11 @@ def sparse_row_scatter(table, rows, ids, vals, bi: int = 512,
 
     table f32[M, I] += scatter(rows i32[U], ids i32[U, W] PAD=-1,
     vals f32[U, W]); returns the updated table (aliased via
-    ``input_output_aliases``).  Duplicate rows are handled (the tile plan sorts every (row, tile)
-    block's visits onto consecutive grid steps, accumulating).  Requires
-    I % bi == 0 and ``t_max`` >= the largest per-row touched-tile count
-    (None picks the always-safe ``min(W, I/bi)``); the ops.py dispatcher
-    selects both / falls back to the XLA reference.
+    ``input_output_aliases``).  Duplicate rows are handled (the tile
+    plan sorts every (row, tile) block's visits onto consecutive grid
+    steps, accumulating).  Requires I % bi == 0 and ``t_max`` >= the
+    largest per-row touched-tile count (None picks the always-safe
+    ``min(W, I/bi)``); the ops.py dispatcher selects both.
     """
     m, n_items = table.shape
     u, w = ids.shape
@@ -100,39 +114,42 @@ def sparse_row_scatter(table, rows, ids, vals, bi: int = 512,
     t_max = max(1, min(t_max, w, n_tiles))
     order = jnp.argsort(rows, stable=True)
     rows_s = jnp.clip(rows[order], 0, m - 1).astype(jnp.int32)
-    ids_s = ids[order]
-    vals_s = jnp.where(ids_s >= 0, vals[order], 0.0)
+    ids_s = ids[order].astype(jnp.int32)
+    vals_s = jnp.where(ids_s >= 0, vals[order], 0.0).astype(jnp.float32)
     plan = build_plan(rows_s, ids_s, bi=bi, t_max=t_max, order="target")
+    pad = -m % 8
+    if interpret and pad:
+        # the chip's HBM layout rounds rows up to the 8-row tile, so the
+        # last group's DMA stays in bounds there; the interpreter needs
+        # the same rows to exist
+        table = jnp.pad(table, ((0, pad), (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(u, t_max),
         in_specs=[
-            pl.BlockSpec((1, w),
-                         lambda r, t, pb, pr, pt, pv: (pb[r * t_max + t], 0)),
-            pl.BlockSpec((1, w),
-                         lambda r, t, pb, pr, pt, pv: (pb[r * t_max + t], 0)),
-            pl.BlockSpec((1, bi),
-                         lambda r, t, pb, pr, pt, pv: (pr[r * t_max + t],
-                                                       pt[r * t_max + t])),
+            pl.BlockSpec((u, w), lambda r, t, pb, pr, pt, pv: (0, 0)),
+            pl.BlockSpec((u, w), lambda r, t, pb, pr, pt, pv: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, bi),
-                               lambda r, t, pb, pr, pt, pv:
-                               (pr[r * t_max + t], pt[r * t_max + t])),
-        scratch_shapes=[pltpu.VMEM((bi,), jnp.float32)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((8, bi), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, bi=bi, t_max=t_max),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         input_output_aliases={6: 0},   # table (after prefetch + ids/vals)
         interpret=interpret,
     )(plan.batch, plan.row, plan.tile, plan.valid, ids_s, vals_s, table)
+    return out[:m] if interpret and pad else out
 
 
 # Kernel contract (DESIGN.md §10.1).  The (U, T_max) grid axes are
 # plan-driven (neither cdiv nor exact division of an array axis);
 # divisible=True records the I % bi == 0 precondition asserted above.
+# The DMA semaphore is not a buffer and is not listed.
 register(KernelContract(
     module="repro.kernels.sparse_row_scatter",
     entry="sparse_row_scatter",
@@ -142,5 +159,5 @@ register(KernelContract(
     divisible=True,
     accumulators=("float32",),
     vmem_model=_avmem.sparse_row_scatter_block_bytes,
-    max_shapes={"w": 4096, "bi": 512},
+    max_shapes={"u": 256, "w": 2048, "bi": 512},
 ))

@@ -20,8 +20,6 @@ import traceback     # noqa: E402
 
 import jax           # noqa: E402
 
-from repro import compat                                # noqa: E402
-
 from repro.configs import REGISTRY, get_arch            # noqa: E402
 from repro.launch.mesh import (HBM_PER_CHIP, make_production_mesh,
                                make_rules)              # noqa: E402
@@ -37,7 +35,7 @@ def run_cell(arch_name: str, shape: str, multi_pod: bool,
     builder = arch.cells[shape]
     t0 = time.time()
     prog = builder(mesh, rules)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(prog.fn, in_shardings=prog.in_shardings,
                          donate_argnums=prog.donate_argnums)
         lowered = jitted.lower(*prog.args)

@@ -29,6 +29,7 @@ from repro.core.types import KIND_ADD_BASKET
 from repro.data import synthetic
 from repro.kernels import ops
 from repro.streaming import Event, StateStore, StoreConfig, StreamingEngine
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -125,4 +126,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

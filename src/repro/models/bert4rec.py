@@ -15,7 +15,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -245,7 +244,7 @@ def serve_step(params, batch, c: Bert4RecConfig, top_n: int = 20,
             tv, tp_ = jax.lax.top_k(cv, top_n)
             return tv, jnp.take_along_axis(ci, tp_, axis=1)
 
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(b_ax, None), P(tp_ax, None), P(tp_ax)),
             out_specs=(P(b_ax, None), P(b_ax, None)),

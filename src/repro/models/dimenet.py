@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from jax.sharding import PartitionSpec as P
 
 
@@ -225,7 +224,7 @@ def forward_sharded(params, batch, c: DimeNetConfig, mesh, rules):
     dyn = dict(batch)
     pspecs = jax.tree.map(lambda s: P(*([None] * len(s.shape))),
                           params, is_leaf=lambda x: hasattr(x, "shape"))
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, in_specs), out_specs=n_spec,
         check_vma=False)(params, dyn)

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 from repro.parallel.sharding import ShardingRules, batch_axes
 
 
@@ -597,7 +595,7 @@ def moe_block(x, layer, c: TransformerConfig, mesh: Optional[Mesh],
             # barrier first: keeps XLA's CPU bf16-dot legalization from
             # commuting converts above the per-layer slice and hoisting a
             # full-depth f32 weight stack out of the layer scan
-            xl, router, wg, wu, wd = compat.optimization_barrier(
+            xl, router, wg, wu, wd = jax.lax.optimization_barrier(
                 (xl, router, wg, wu, wd))
             # gather the FSDP dim (D) of the expert weights
             if fs_ok:
@@ -620,7 +618,7 @@ def moe_block(x, layer, c: TransformerConfig, mesh: Optional[Mesh],
             out_spec = P(tok_axes, None)
         else:
             out_spec = P(batch_ax, None)
-        out = compat.shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(batch_ax, None),
                       P(None, None), wspec_df, wspec_df, wspec_fd),
@@ -715,7 +713,7 @@ def _scan_layers(x, layers, c, positions, windows, ffn_fn, attn_fn,
         # the while loop, materializing ALL layers' weights in f32 at once
         # (measured +12 GiB on deepseek decode).  TPU never inserts these
         # converts; the barrier makes the portable lowering match.
-        inputs = compat.optimization_barrier(inputs)
+        inputs = jax.lax.optimization_barrier(inputs)
         if hspec is not None:
             h = _constrain(h, mesh, hspec)
         if caches is not None:

@@ -23,8 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def quantize_int8(x):
     """Per-tensor symmetric int8. Returns (q: int8, scale: f32)."""
@@ -139,8 +137,8 @@ def hierarchical_psum_mean(x, intra_axes, inter_axis, err=None):
     n_intra = 1
     for a in (intra_axes if isinstance(intra_axes, (tuple, list))
               else (intra_axes,)):
-        n_intra *= compat.axis_size(a)
-    n_inter = compat.axis_size(inter_axis)
+        n_intra *= jax.lax.axis_size(a)
+    n_inter = jax.lax.axis_size(inter_axis)
     # intra-pod reduce-scatter over the flattened leading dim when
     # divisible; otherwise a plain psum (small tensors)
     flat = x.reshape(-1)

@@ -55,7 +55,7 @@ from repro.streaming.async_checkpoint import AsyncCheckpointer
 from repro.streaming.state_store import (CorruptCheckpointError, StateStore,
                                          StoreConfig, atomic_write_json,
                                          load_checkpoint_arrays,
-                                         load_json_checked)
+                                         load_json_checked, pad_item_leaves)
 
 
 # -- device-side step-summary programs (DESIGN.md §12) ----------------------
@@ -709,12 +709,10 @@ class StreamingEngine:
         bucket (validity-masked, so padding contributes count 1) to
         bound compiled shapes.  Sound because distinct tiles <= distinct
         ids and the supports are supersets of what the appliers
-        construct; empty when the kernels run the XLA reference.
+        construct.
         """
         from repro.kernels import ops
-        bi = ops.plan_bi(self.store.cfg.n_items)
-        if bi is None:       # kernels fall back to the XLA reference
-            return {}
+        bi = ops.plan_bi(self.store.cfg.n_cols)
         st = self.store.state
         w = self.store.cfg.max_basket_size
 
@@ -1035,11 +1033,12 @@ class StreamingEngine:
             corpus_q, c_scale = self.store.quantized_corpus()
             recs = knn.recommend_for_users_quant(
                 corpus_q, c_scale, jnp.asarray(ids.astype(np.int32)),
-                k=k, alpha=alpha, topn=topn)
+                k=k, alpha=alpha, topn=topn, n_items=self.store.cfg.n_items)
         else:
             recs = knn.recommend_for_users(
                 self.store.corpus(), jnp.asarray(ids.astype(np.int32)),
-                k=k, alpha=alpha, topn=topn, metric=metric)
+                k=k, alpha=alpha, topn=topn, metric=metric,
+                n_items=self.store.cfg.n_items)
         self.metrics.serve_requests += 1
         # alpha included: it is a static (compile-triggering) arg of
         # the Pallas serving path, so per-request alphas must show up
@@ -1172,7 +1171,8 @@ _SHARD_MANIFEST = "SHARDS"
 
 # every StreamState leaf, derived so a new field cannot silently be
 # dropped by the resharding assembler
-_STATE_LEAVES = tuple(f.name for f in dataclasses.fields(StreamState))
+_STATE_LEAVES = tuple(f.name for f in dataclasses.fields(StreamState)
+                      if f.name != "n_real")
 
 
 class ShardedStreamingEngine:
@@ -1486,11 +1486,13 @@ class ShardedStreamingEngine:
                 raise ValueError("quantized serving is euclidean-only")
             recs = knn.sharded_recommend_for_users_quant(
                 self.quantized_corpora(), ids, k=k, alpha=alpha,
-                topn=topn, n_shards=self.spec.n_shards)
+                topn=topn, n_shards=self.spec.n_shards,
+                n_items=self.params.n_items)
         else:
             recs = knn.sharded_recommend_for_users(
                 self.corpora(), ids, k=k, alpha=alpha,
-                topn=topn, n_shards=self.spec.n_shards, metric=metric)
+                topn=topn, n_shards=self.spec.n_shards, metric=metric,
+                n_items=self.params.n_items)
         return np.asarray(recs)[:q_n]
 
     # -- recovery ---------------------------------------------------------------
@@ -1670,7 +1672,8 @@ class ShardedStreamingEngine:
                     f"shard checkpoint {d} carries no exactly-once log; "
                     "refusing to reshard (replay could double-apply)")
             metas.append(meta)
-            leaves.append(lv)
+            leaves.append(pad_item_leaves(
+                lv, self.shards[0].store.cfg.n_cols))
             old_logs.append(log)
         n_total = sum(lv["n_baskets"].shape[0] for lv in leaves)
         if n_total != spec.n_users:
@@ -1699,7 +1702,8 @@ class ShardedStreamingEngine:
                     out[s][name][nr[m]] = src[m]
         for s, sh in enumerate(self.shards):
             sh.store.install_state(StreamState(
-                **{k: jnp.asarray(v) for k, v in out[s].items()}))
+                **{k: jnp.asarray(v) for k, v in out[s].items()},
+                n_real=sh.store.cfg.n_items))
             sh._reset_log()
         self._legacy.append({"n_shards": n_old, "logs": [
             {"watermark": lg["watermark"],

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.types import StreamState, _pow2_pad
+from repro.core.types import StreamState, _pow2_pad, lane_padded
 from repro.optim.compression import quantize_int8_rows
 from repro.streaming import faults
 
@@ -81,6 +81,16 @@ class StoreConfig:
     # transient errors back off base·2^i and then surface (DESIGN.md §9)
     io_retries: int = 4
     io_retry_base_s: float = 0.005
+
+    @property
+    def n_cols(self) -> int:
+        """Width of the dense leaves: ``n_items`` padded to the lane tile.
+
+        The tile-planned update kernels and the serving kernels run on
+        lane-aligned item tiles; ``n_items`` stays the real catalogue
+        (event validation, checkpoints, recommendations).
+        """
+        return lane_padded(self.n_items)
 
 
 def _fsync_dir(path: str) -> None:
@@ -290,6 +300,24 @@ def load_checkpoint_arrays(
         + "; ".join(errors))
 
 
+_DENSE_LEAVES = ("user_vecs", "last_group_vecs")
+
+
+def pad_item_leaves(leaves: Dict[str, np.ndarray],
+                    n_cols: int) -> Dict[str, np.ndarray]:
+    """Zero-pad checkpointed dense leaves to the store's ``n_cols``.
+
+    Checkpoints hold the real catalogue columns only (the pad columns
+    are zero by construction), so a commit restores into any padding.
+    """
+    out = dict(leaves)
+    for name in _DENSE_LEAVES:
+        v = out[name]
+        if v.shape[1] < n_cols:
+            out[name] = np.pad(v, ((0, 0), (0, n_cols - v.shape[1])))
+    return out
+
+
 def state_shardings(cfg: StoreConfig, mesh: Any) -> StreamState:
     """PartitionSpecs for every leaf of the state pytree."""
     u = P(cfg.user_axes)
@@ -304,6 +332,7 @@ def state_shardings(cfg: StoreConfig, mesh: Any) -> StreamState:
         err_mult=NamedSharding(mesh, u),
         uv_scale=NamedSharding(mesh, u),
         lgv_scale=NamedSharding(mesh, u),
+        n_real=cfg.n_items,
     )
 
 
@@ -431,7 +460,11 @@ class StateStore:
         return self._frozen_corpus is not None
 
     def corpus(self) -> jax.Array:
-        """The materialized true-value corpus f32[n_users, n_items].
+        """The materialized true-value corpus f32[n_users, n_cols].
+
+        The lane padding is kept (its columns are zero): the serving
+        kernels read lane-aligned tiles, and callers pass the real
+        ``n_items`` so pad columns are never recommended.
 
         First call (or after ``invalidate_all``) densifies everything;
         subsequent calls refresh only rows dirtied since the last call.
@@ -452,14 +485,14 @@ class StateStore:
         if self._frozen_corpus is not None:
             return self._frozen_corpus
         if self._corpus is None:
-            self._corpus = self.state.materialized_user_vecs()
+            self._corpus = self.state.materialized_user_vecs(padded=True)
             self._dirty.clear()
             self.corpus_full_builds += 1
         elif len(self._dirty) > self.cfg.corpus_rebuild_frac \
                 * self.cfg.n_users:
             # past the crossover one full rebuild is cheaper than a
             # scattered refresh of most rows (and compiles exactly once)
-            self._corpus = self.state.materialized_user_vecs()
+            self._corpus = self.state.materialized_user_vecs(padded=True)
             self._dirty.clear()
             self.corpus_full_builds += 1
             self.corpus_threshold_rebuilds += 1
@@ -564,29 +597,28 @@ class StateStore:
         """
         rows = np.asarray(list(users), np.int64)
         st = self.state
+
+        def take(leaf) -> np.ndarray:
+            # index on device: only the requested rows cross to the host
+            return np.asarray(leaf[jnp.asarray(rows, jnp.int32)])
+
         out: Dict[str, float] = {
             "user_vec_absmax": float(
-                np.abs(np.asarray(st.user_vecs)[rows]).max(initial=0.0)),
+                np.abs(take(st.user_vecs)).max(initial=0.0)),
             "last_group_absmax": float(
-                np.abs(np.asarray(st.last_group_vecs)[rows])
-                .max(initial=0.0)),
-            "history_ids": float(
-                (np.asarray(st.history)[rows] >= 0).sum()),
-            "n_baskets": float(np.asarray(st.n_baskets)[rows]
-                               .sum(initial=0)),
-            "n_groups": float(np.asarray(st.n_groups)[rows]
-                              .sum(initial=0)),
+                np.abs(take(st.last_group_vecs)).max(initial=0.0)),
+            "history_ids": float((take(st.history) >= 0).sum()),
+            "n_baskets": float(take(st.n_baskets).sum(initial=0)),
+            "n_groups": float(take(st.n_groups).sum(initial=0)),
         }
         if self._corpus is not None:
             out["corpus_absmax"] = float(
-                np.abs(np.asarray(self._corpus)[rows]).max(initial=0.0))
+                np.abs(take(self._corpus)).max(initial=0.0))
         if self._corpus_q is not None:
-            out["quant_nonzero"] = float(
-                (np.asarray(self._corpus_q)[rows] != 0).sum())
+            out["quant_nonzero"] = float((take(self._corpus_q) != 0).sum())
         if self._frozen_corpus is not None:
             out["frozen_absmax"] = float(
-                np.abs(np.asarray(self._frozen_corpus)[rows])
-                .max(initial=0.0))
+                np.abs(take(self._frozen_corpus)).max(initial=0.0))
         return out
 
     # -- persistence (exactly-once recovery substrate) -----------------------
@@ -601,12 +633,15 @@ class StateStore:
         a background writer holding a view would serialize garbage
         (read-after-free).  The deep copy is the "snapshot" half of
         snapshot-then-write (DESIGN.md §12) and the only O(state) cost
-        that stays on the caller's hot path.
+        that stays on the caller's hot path.  The dense leaves are saved
+        without their lane padding (see :func:`pad_item_leaves`).
         """
         st = self.state
+        n = self.cfg.n_items
         return {
-            "user_vecs": np.array(st.user_vecs, copy=True),
-            "last_group_vecs": np.array(st.last_group_vecs, copy=True),
+            "user_vecs": np.array(np.asarray(st.user_vecs)[:, :n]),
+            "last_group_vecs": np.array(
+                np.asarray(st.last_group_vecs)[:, :n]),
             "history": np.array(st.history, copy=True),
             "group_sizes": np.array(st.group_sizes, copy=True),
             "n_baskets": np.array(st.n_baskets, copy=True),
@@ -792,6 +827,8 @@ class StateStore:
         self.corruption_detected += len(rec.get("skipped", ()))
         self.last_restored_meta = meta
         step = meta["step"]
+        leaves = pad_item_leaves(leaves, self.cfg.n_cols)
         self.install_state(StreamState(
-            **{k: jax.numpy.asarray(v) for k, v in leaves.items()}))
+            **{k: jax.numpy.asarray(v) for k, v in leaves.items()},
+            n_real=self.cfg.n_items))
         return step

@@ -97,8 +97,10 @@ def test_pallas_site_extraction_on_real_kernel():
     mono = sites["knn_topk"]
     assert len(mono.grid) == 2 and mono.grid_parsed
     assert mono.kernel_body == "_kernel"
-    assert [s.arity for s in mono.in_specs] == [2, 2, 2, 2]
-    assert mono.scratch_dtypes == ["float32", "int32"]
+    assert [s.arity for s in mono.in_specs] == [2, 2, 2]
+    assert mono.scratch_dtypes == ["float32", "float32", "int32",
+                                   "float32", "int32"]
     dt = sites["knn_topk_dtiled"]
     assert len(dt.grid) == 3
-    assert dt.scratch_dtypes == ["float32", "float32", "int32"]
+    assert dt.scratch_dtypes == ["float32", "float32", "float32", "int32",
+                                 "float32", "int32"]

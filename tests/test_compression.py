@@ -41,7 +41,6 @@ def test_hierarchical_psum_matches_plain():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
-from repro import compat
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.compression import hierarchical_psum_mean
@@ -52,8 +51,8 @@ def body(xl):
     out, _ = hierarchical_psum_mean(xl[0], "data", "pod", err=None)
     return out[None]
 
-with compat.set_mesh(mesh):
-    out = jax.jit(compat.shard_map(body, mesh=mesh,
+with jax.set_mesh(mesh):
+    out = jax.jit(jax.shard_map(body, mesh=mesh,
                                 in_specs=P(("pod", "data"), None),
                                 out_specs=P(("pod", "data"), None),
                                 check_vma=False))(x)

@@ -18,7 +18,6 @@ HEADER = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
-from repro import compat
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel.sharding import ShardingRules
@@ -34,7 +33,7 @@ from repro.core import knn
 q = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
 c = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
 ref = knn.predict(q, c, k=5, alpha=0.7, exclude_self=False)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     cd = jax.device_put(c, NamedSharding(mesh, P(("data","model"), None)))
     out = jax.jit(lambda q, c: knn.distributed_predict(
         q, c, 5, 0.7, mesh, rules))(q, cd)
@@ -57,7 +56,7 @@ layer = {k: jax.random.normal(jax.random.PRNGKey(i), v, jnp.float32)*0.1
          if k.startswith(("router", "we_"))}
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 64), jnp.float32)
 out_local = moe_block(x, layer, c, None, None)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
     ls = {"router": jax.device_put(layer["router"],
                                    NamedSharding(mesh, P(None, None))),
@@ -81,7 +80,7 @@ c = bert4rec.Bert4RecConfig(n_items=1000, embed_dim=32, n_blocks=2,
 params = bert4rec.init_params(c, jax.random.PRNGKey(0))
 ids = jnp.asarray(rng.integers(2, 900, (8, 16)), jnp.int32)
 v0, i0 = bert4rec.serve_step(params, {"ids": ids}, c, top_n=10)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     v1, i1 = jax.jit(lambda p, b: bert4rec.serve_step(
         p, b, c, top_n=10, mesh=mesh, rules=rules))(params, {"ids": ids})
 np.testing.assert_allclose(np.asarray(v0), np.asarray(v1), atol=1e-4)
@@ -107,7 +106,7 @@ pspecs = T.param_pspecs(c, mesh, rules)
 opt = adamw(total_steps=5)
 batch = {"tokens": jnp.ones((8, 32), jnp.int32),
          "labels": jnp.ones((8, 32), jnp.int32)}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     params = jax.tree.map(lambda x, s: jax.device_put(
         x, NamedSharding(mesh, s)), params, pspecs,
         is_leaf=lambda x: isinstance(x, jax.Array))

@@ -11,7 +11,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import sys
 sys.path.insert(0, "src")
-from repro import compat
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel.sharding import ShardingRules
@@ -32,7 +31,7 @@ opt_state = jax.eval_shape(opt.init, params)
 batch = {"tokens": jax.ShapeDtypeStruct((16, 32), jnp.int32),
          "labels": jax.ShapeDtypeStruct((16, 32), jnp.int32)}
 bshard = {k: NamedSharding(mesh, P(("data",), None)) for k in batch}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     fn = T.make_train_step(c, opt, mesh, rules)
     lowered = jax.jit(fn, in_shardings=(
         named(mesh, pspecs), named(mesh, adamw_state_pspecs(pspecs)),

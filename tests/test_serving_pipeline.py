@@ -143,7 +143,7 @@ def test_blend_topn_onehot_matches_gather_path(rng):
     uids = jnp.asarray(rng.choice(m, q_n, replace=False).astype(np.int32))
     idx = jnp.asarray(rng.integers(0, m, (q_n, k)), jnp.int32)
     v, i = blend_topn_onehot(corpus, uids, idx, alpha=0.7, topn=6,
-                             bq=8, bm=32, bi=32, kc=2, interpret=True)
+                             bq=8, bm=32, bi=32, interpret=True)
     pred = (0.7 * corpus[uids]
             + 0.3 * jnp.mean(corpus[idx], axis=1))
     rv, ri = jax.lax.top_k(pred, 6)
@@ -160,7 +160,7 @@ def test_blend_topn_onehot_duplicate_item_tiebreak(rng):
     uids = jnp.asarray(rng.choice(m, q_n, replace=False).astype(np.int32))
     idx = jnp.asarray(rng.integers(0, m, (q_n, k)), jnp.int32)
     v, i = blend_topn_onehot(corpus, uids, idx, alpha=0.7, topn=10,
-                             bq=4, bm=16, bi=8, kc=2, interpret=True)
+                             bq=4, bm=16, bi=8, interpret=True)
     pred = 0.7 * corpus[uids] + 0.3 * jnp.mean(corpus[idx], axis=1)
     np.testing.assert_array_equal(np.asarray(i),
                                   np.asarray(jax.lax.top_k(pred, 10)[1]))

@@ -191,22 +191,25 @@ def test_replay_oracles_match_refs(rng):
 
 
 def test_ops_dispatch_fallback_and_t_max_selection(rng):
-    """n_items % 128 != 0 falls back to the XLA reference; concrete
-    batches get a measured (pow2) T_max below the static bound."""
+    """n_items % 128 != 0 has no tiling: the kernel path refuses it
+    (no silent switch to the reference) while the reference serves it;
+    concrete batches get a measured (pow2) T_max below the static
+    bound."""
     table = jnp.asarray(rng.normal(size=(8, 130)), jnp.float32)  # 130 % 128
     rows = jnp.asarray([1, 2], jnp.int32)
     ids = jnp.asarray(rng.integers(-1, 130, (2, 6)), jnp.int32)
     vals = jnp.asarray(rng.normal(size=(2, 6)), jnp.float32)
-    assert ops._plan_dims(130, ids) is None
-    out = ops.sparse_row_scatter(table, rows, ids, vals, impl="interpret")
+    with pytest.raises(ValueError, match="divisible by 128"):
+        ops._plan_dims(130, ids)
+    with pytest.raises(ValueError, match="divisible by 128"):
+        ops.sparse_row_scatter(table, rows, ids, vals, impl="interpret")
+    with pytest.raises(ValueError, match="divisible by 128"):
+        ops.sparse_row_gather(table, rows, ids, impl="interpret")
     np.testing.assert_allclose(
-        np.asarray(out),
+        np.asarray(ops.sparse_row_scatter(table, rows, ids, vals,
+                                          impl="ref")),
         np.asarray(ref.sparse_row_scatter_ref(table, rows, ids, vals)),
         atol=1e-5)
-    got = ops.sparse_row_gather(table, rows, ids, impl="interpret")
-    np.testing.assert_array_equal(
-        np.asarray(got),
-        np.asarray(ref.sparse_row_gather_ref(table, rows, ids)))
 
     # concrete ids -> measured t_max; tracers -> static worst case
     ids_c = jnp.asarray([[0, 1, 2, 3], [128, 129, 130, 131]], jnp.int32)

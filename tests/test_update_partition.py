@@ -440,12 +440,12 @@ def test_store_corpus_cache_tracks_state(rng):
     ref = RefEngine(P, dtype=np.float32)
     events = random_mixed_events(rng, ref, 80, M)
     np.testing.assert_allclose(np.asarray(store.corpus()),
-                               np.zeros((M, P.n_items)))   # cold build
+                               np.zeros((M, store.cfg.n_cols)))  # cold
     eng.submit(events)
     while eng.step():
         np.testing.assert_allclose(
             np.asarray(store.corpus()),
-            np.asarray(store.state.materialized_user_vecs()),
+            np.asarray(store.state.materialized_user_vecs(padded=True)),
             rtol=1e-6, atol=1e-7)
     assert store.corpus_full_builds == 1
     # each batch dirties <= batch_size rows; far fewer refreshes than a
@@ -455,7 +455,8 @@ def test_store_corpus_cache_tracks_state(rng):
     store.invalidate_all()
     np.testing.assert_allclose(
         np.asarray(store.corpus()),
-        np.asarray(store.state.materialized_user_vecs()), rtol=1e-6,
+        np.asarray(store.state.materialized_user_vecs(padded=True)),
+        rtol=1e-6,
         atol=1e-7)
     assert store.corpus_full_builds == 2
 
@@ -484,7 +485,8 @@ def test_store_corpus_rebuild_threshold_crossover(rng):
     touch(range(3))                              # 3/8 <= 0.5: row refresh
     np.testing.assert_allclose(
         np.asarray(store.corpus()),
-        np.asarray(store.state.materialized_user_vecs()), rtol=1e-6,
+        np.asarray(store.state.materialized_user_vecs(padded=True)),
+        rtol=1e-6,
         atol=1e-7)
     assert store.corpus_threshold_rebuilds == 0
     assert store.corpus_rows_refreshed >= 3
@@ -493,7 +495,8 @@ def test_store_corpus_rebuild_threshold_crossover(rng):
     touch(range(5))                              # 5/8 > 0.5: full rebuild
     np.testing.assert_allclose(
         np.asarray(store.corpus()),
-        np.asarray(store.state.materialized_user_vecs()), rtol=1e-6,
+        np.asarray(store.state.materialized_user_vecs(padded=True)),
+        rtol=1e-6,
         atol=1e-7)
     assert store.corpus_threshold_rebuilds == 1
     assert store.corpus_full_builds == 2
