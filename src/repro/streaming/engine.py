@@ -52,6 +52,7 @@ from repro.core.updates import (SCALE_CEIL, SCALE_FLOOR,
 from repro.kernels import tile_plan
 from repro.parallel.sharding import UserShardSpec
 from repro.streaming.async_checkpoint import AsyncCheckpointer
+from repro.streaming.spans import span, trace_gc
 from repro.streaming.state_store import (CorruptCheckpointError, StateStore,
                                          StoreConfig, atomic_write_json,
                                          load_checkpoint_arrays,
@@ -165,6 +166,11 @@ class AdmissionResult:
             self.first_rejected_seqno = other.first_rejected_seqno
         return self
 
+    @property
+    def handed_in(self) -> int:
+        """Events handed to ``submit``: each lands in one count."""
+        return self.admitted + self.deduped + self.quarantined + self.rejected
+
 
 def _pad_request(user_ids) -> tuple:
     """Pad a serving request to its pow2 bucket (DESIGN.md §8.3).
@@ -245,7 +251,6 @@ class EngineMetrics:
     # that bucket was seen before); shrinks are hysteresis-gated
     bucket_grows: int = 0
     bucket_shrinks: int = 0
-    last_batch_seconds: float = 0.0
     # serving request batches answered via `recommend`, and the number
     # of distinct (pow2 query bucket, topn, k, metric) shapes they
     # compiled — bounded at O(log max_batch) per parameter set by the
@@ -386,6 +391,7 @@ class StreamingEngine:
         # the host-side view of `kernels.ops.serving_cache_size`
         self._serve_shapes: set = set()
         self.metrics = EngineMetrics()
+        trace_gc()
         if stability_target_rel_err is not None:
             self.err_threshold = stability.refresh_threshold(
                 stability_target_rel_err, np.finfo(np.float32).eps)
@@ -491,6 +497,18 @@ class StreamingEngine:
             raise ValueError(f"on_invalid={on_invalid!r}")
         if on_overflow not in ("raise", "shed"):
             raise ValueError(f"on_overflow={on_overflow!r}")
+        with span("engine.submit") as sp:
+            res = self._admit(events, on_invalid)
+            sp.set_metadata(n=res.handed_in)
+        if res.rejected and on_overflow == "raise":
+            raise Backpressure(res.admitted, res.rejected,
+                               res.first_rejected_seqno, self._n_pending)
+        return res
+
+    def _admit(self, events: Iterable[Event],
+               on_invalid: str) -> AdmissionResult:
+        """`submit`'s dedup, validation and admission, overflow shed and
+        counted (the sharded router routes each event here)."""
         res = AdmissionResult()
         for ev in events:
             explicit = ev.seqno >= 0
@@ -538,9 +556,6 @@ class StreamingEngine:
             self._max_delivered = max(self._max_delivered, ev.seqno)
             self._enqueue(ev)
             res.admitted += 1
-        if res.rejected and on_overflow == "raise":
-            raise Backpressure(res.admitted, res.rejected,
-                               res.first_rejected_seqno, self._n_pending)
         return res
 
     def add_basket(self, user: int, items: Sequence[int]) -> None:
@@ -574,23 +589,34 @@ class StreamingEngine:
         events plus one O(n_items) row scrub.  Idempotent.
         """
         t0 = time.perf_counter()
-        self.run_until_drained()
-        # index on device, fetch one scalar — np.asarray(n_baskets)[user]
-        # would pull the whole O(n_users) leaf to read one element
-        nb = int(jax.device_get(self.store.state.n_baskets[user]))
-        first = self._next_seqno
-        if nb:
-            self.submit([Event(KIND_DEL_BASKET, user, pos=p)
-                         for p in range(nb - 1, -1, -1)])
-            self.run_until_drained()
-        self._scrub_user(user)
-        purged = self._purge_dead_letters(user)
+        with span("engine.forget", user=user) as sp:
+            with span("engine.forget.drain"):
+                self.run_until_drained()
+            # index on device, fetch one scalar —
+            # np.asarray(n_baskets)[user] would pull the whole
+            # O(n_users) leaf to read one element
+            with span("engine.forget.lookup"):
+                nb = int(jax.device_get(self.store.state.n_baskets[user]))
+            sp.set_metadata(baskets=nb)
+            first = self._next_seqno
+            if nb:
+                with span("engine.forget.emit"):
+                    self.submit([Event(KIND_DEL_BASKET, user, pos=p)
+                                 for p in range(nb - 1, -1, -1)])
+                with span("engine.forget.delete"):
+                    self.run_until_drained()
+            with span("engine.forget.scrub"):
+                self._scrub_user(user)
+            with span("engine.forget.purge"):
+                purged = self._purge_dead_letters(user)
+            latency_s = time.perf_counter() - t0
+            with span("engine.forget.residue"):
+                residue = self.store.row_residue([user])
         return ForgetReceipt(
             user=user, n_baskets_deleted=nb,
             seqnos=tuple(range(first, first + nb)),
-            purged_dead_letters=purged,
-            latency_s=time.perf_counter() - t0,
-            residue=self.store.row_residue([user]))
+            purged_dead_letters=purged, latency_s=latency_s,
+            residue=residue)
 
     def _scrub_user(self, user: int) -> None:
         """Zero a forgotten user's row exactly, caches included.
@@ -693,7 +719,8 @@ class StreamingEngine:
         add more.
         """
         self.metrics.host_fetches += 1
-        return jax.device_get(tree)
+        with span("engine.fetch"):
+            return jax.device_get(tree)
 
     def _dispatch_tile_bounds(self, adds, delb, deli) -> Dict[int, jax.Array]:
         """Dispatch per-kind device touched-tile-bound programs (§3.3).
@@ -801,38 +828,47 @@ class StreamingEngine:
                                      (KIND_DEL_ITEM, deli)) if evs})
         b = self.store.cfg.max_basket_size
         if adds:
-            batch = AddBatch.build(
-                [ev.user for ev in adds], [ev.items for ev in adds], b,
-                pad_to=self._bucket(KIND_ADD_BASKET, len(adds)))
+            pad = self._bucket(KIND_ADD_BASKET, len(adds))
+            with span("engine.build", kind=KIND_ADD_BASKET):
+                batch = AddBatch.build(
+                    [ev.user for ev in adds], [ev.items for ev in adds], b,
+                    pad_to=pad)
             # the counted variant surfaces capacity drops (masked to
             # no-ops by the guard) from the same fused program; the
             # count ACCUMULATES on device and rides the next step's
             # summary fetch — int(dropped) here would be a second
             # per-batch transfer
-            self.store.state, dropped = apply_add_batch_counted(
-                self.store.state, batch, self.params,
-                t_max_cap=hints.get(KIND_ADD_BASKET, 0))
+            with span("engine.apply", kind=KIND_ADD_BASKET, bucket=pad):
+                self.store.state, dropped = apply_add_batch_counted(
+                    self.store.state, batch, self.params,
+                    t_max_cap=hints.get(KIND_ADD_BASKET, 0))
             self._dropped_dev = (dropped if self._dropped_dev is None
                                  else self._dropped_dev + dropped)
         if delb:
-            batch = DelBasketBatch.build(
-                [ev.user for ev in delb], [ev.pos for ev in delb],
-                pad_to=self._bucket(KIND_DEL_BASKET, len(delb)))
-            self.store.state = apply_del_basket_batch(
-                self.store.state, batch, self.params,
-                t_max_cap=hints.get(KIND_DEL_BASKET, 0))
+            pad = self._bucket(KIND_DEL_BASKET, len(delb))
+            with span("engine.build", kind=KIND_DEL_BASKET):
+                batch = DelBasketBatch.build(
+                    [ev.user for ev in delb], [ev.pos for ev in delb],
+                    pad_to=pad)
+            with span("engine.apply", kind=KIND_DEL_BASKET, bucket=pad):
+                self.store.state = apply_del_basket_batch(
+                    self.store.state, batch, self.params,
+                    t_max_cap=hints.get(KIND_DEL_BASKET, 0))
         if deli:
-            batch = DelItemBatch.build(
-                [ev.user for ev in deli], [ev.pos for ev in deli],
-                [ev.item for ev in deli],
-                pad_to=self._bucket(KIND_DEL_ITEM, len(deli)))
-            self.store.state = apply_del_item_batch(
-                self.store.state, batch, self.params,
-                t_max_cap=hints.get(KIND_DEL_ITEM, 0))
+            pad = self._bucket(KIND_DEL_ITEM, len(deli))
+            with span("engine.build", kind=KIND_DEL_ITEM):
+                batch = DelItemBatch.build(
+                    [ev.user for ev in deli], [ev.pos for ev in deli],
+                    [ev.item for ev in deli], pad_to=pad)
+            with span("engine.apply", kind=KIND_DEL_ITEM, bucket=pad):
+                self.store.state = apply_del_item_batch(
+                    self.store.state, batch, self.params,
+                    t_max_cap=hints.get(KIND_DEL_ITEM, 0))
         # serving-corpus cache: only the APPLIED rows changed (§3.6) —
         # quarantined events touched nothing
-        self.store.invalidate_users(
-            [ev.user for ev in adds + delb + deli])
+        with span("engine.invalidate"):
+            self.store.invalidate_users(
+                [ev.user for ev in adds + delb + deli])
 
     def _apply_maintenance(self, err_max, lo, hi) -> None:
         """Stability refreshes + scale renorm from the probe scalars.
@@ -843,28 +879,37 @@ class StreamingEngine:
         locate the offending rows; both are rare by construction
         (stability §3.3; the scale drift analysis in ``__init__``).
         """
-        if self.err_threshold is not None and err_max > self.err_threshold:
-            err = np.asarray(self._fetch(self.store.state.err_mult))
-            bad = np.nonzero(err > self.err_threshold)[0]
-            if bad.size:
-                self.store.state = refresh_users(
-                    self.store.state, jnp.asarray(bad, jnp.int32),
-                    self.params)
-                self.metrics.refreshes += int(bad.size)
-                # a refresh changes the served values (it resets the
-                # accumulated fp error), so those rows are stale too
-                self.store.invalidate_users(bad)
         floor = SCALE_FLOOR * 1e2   # renormalize well before the bounds
         ceil = SCALE_CEIL * 1e-2
-        if lo < floor or hi > ceil:
-            uv_h, lgv_h = self._fetch((self.store.state.uv_scale,
-                                       self.store.state.lgv_scale))
-            uv_h, lgv_h = np.asarray(uv_h), np.asarray(lgv_h)
-            out = np.nonzero((uv_h < floor) | (lgv_h < floor)
-                             | (uv_h > ceil) | (lgv_h > ceil))[0]
-            self.store.state = renormalize_users(
-                self.store.state, jnp.asarray(out, jnp.int32))
-            self.metrics.renormalizations += int(out.size)
+        refresh = (self.err_threshold is not None
+                   and err_max > self.err_threshold)
+        if not (refresh or lo < floor or hi > ceil):
+            return
+        with span("engine.maintain") as sp:
+            rows = 0
+            if refresh:
+                err = np.asarray(self._fetch(self.store.state.err_mult))
+                bad = np.nonzero(err > self.err_threshold)[0]
+                if bad.size:
+                    self.store.state = refresh_users(
+                        self.store.state, jnp.asarray(bad, jnp.int32),
+                        self.params)
+                    self.metrics.refreshes += int(bad.size)
+                    # a refresh changes the served values (it resets the
+                    # accumulated fp error), so those rows are stale too
+                    self.store.invalidate_users(bad)
+                rows += int(bad.size)
+            if lo < floor or hi > ceil:
+                uv_h, lgv_h = self._fetch((self.store.state.uv_scale,
+                                           self.store.state.lgv_scale))
+                uv_h, lgv_h = np.asarray(uv_h), np.asarray(lgv_h)
+                out = np.nonzero((uv_h < floor) | (lgv_h < floor)
+                                 | (uv_h > ceil) | (lgv_h > ceil))[0]
+                self.store.state = renormalize_users(
+                    self.store.state, jnp.asarray(out, jnp.int32))
+                self.metrics.renormalizations += int(out.size)
+                rows += int(out.size)
+            sp.set_metadata(rows=rows)
 
     def _consume_summary(self, host: dict) -> None:
         """Apply the deferred halves of a fetched step summary."""
@@ -906,25 +951,27 @@ class StreamingEngine:
         dispatches every shard's programs before any shard blocks on
         its fetch (`ShardedStreamingEngine.step`).
         """
-        events = self._cut_batch()
-        adds = [ev for ev in events if ev.kind == KIND_ADD_BASKET]
-        delb = [ev for ev in events if ev.kind == KIND_DEL_BASKET]
-        deli = [ev for ev in events if ev.kind == KIND_DEL_ITEM]
-        st = self.store.state
-        fetch: dict = {}
-        if self._maintenance_due:
-            fetch["probe"] = _maintenance_probe(st.err_mult, st.uv_scale,
-                                                st.lgv_scale)
-        if self._dropped_dev is not None:
-            fetch["dropped"] = self._dropped_dev
-        if delb or deli:
-            idx = jnp.asarray(np.asarray(
-                [ev.user for ev in delb + deli], np.int32))
-            fetch["del_nb"] = st.n_baskets[idx]
-        if events and self.tile_hints:
-            bounds = self._dispatch_tile_bounds(adds, delb, deli)
-            if bounds:
-                fetch["tiles"] = bounds
+        with span("engine.cut"):
+            events = self._cut_batch()
+            adds = [ev for ev in events if ev.kind == KIND_ADD_BASKET]
+            delb = [ev for ev in events if ev.kind == KIND_DEL_BASKET]
+            deli = [ev for ev in events if ev.kind == KIND_DEL_ITEM]
+        with span("engine.summary"):
+            st = self.store.state
+            fetch: dict = {}
+            if self._maintenance_due:
+                fetch["probe"] = _maintenance_probe(
+                    st.err_mult, st.uv_scale, st.lgv_scale)
+            if self._dropped_dev is not None:
+                fetch["dropped"] = self._dropped_dev
+            if delb or deli:
+                idx = jnp.asarray(np.asarray(
+                    [ev.user for ev in delb + deli], np.int32))
+                fetch["del_nb"] = st.n_baskets[idx]
+            if events and self.tile_hints:
+                bounds = self._dispatch_tile_bounds(adds, delb, deli)
+                if bounds:
+                    fetch["tiles"] = bounds
         return events, adds, delb, deli, fetch
 
     def _complete_step(self, prep) -> List[Event]:
@@ -954,15 +1001,15 @@ class StreamingEngine:
         """Cut one micro-batch and apply it (one fused summary fetch)."""
         return self._complete_step(self._prepare_step())
 
-    def _finish_step(self, events: List[Event], t0: float) -> int:
+    def _finish_step(self, events: List[Event]) -> int:
         """Exactly-once log advance + counters for one micro-batch."""
-        for ev in events:
-            self._processed_above.add(ev.seqno)
-        self._advance_watermark()
-        self.metrics.events_processed += len(events)
-        self.metrics.batches += 1
-        self.metrics.last_batch_seconds = time.perf_counter() - t0
-        return len(events)
+        with span("engine.log"):
+            for ev in events:
+                self._processed_above.add(ev.seqno)
+            self._advance_watermark()
+            self.metrics.events_processed += len(events)
+            self.metrics.batches += 1
+            return len(events)
 
     def _advance_watermark(self) -> None:
         """Advance the frontier under the subsequence semantics.
@@ -981,11 +1028,11 @@ class StreamingEngine:
 
     def step(self) -> int:
         """Process one micro-batch. Returns number of events applied."""
-        t0 = time.perf_counter()
-        events = self._begin_step()
-        if not events:
-            return 0
-        return self._finish_step(events, t0)
+        with span("engine.step", step=self.metrics.batches):
+            events = self._begin_step()
+            if not events:
+                return 0
+            return self._finish_step(events)
 
     def run_until_drained(self, max_batches: int = 10_000) -> int:
         """Step until the pending queues empty; returns events applied."""
@@ -1316,6 +1363,18 @@ class ShardedStreamingEngine:
             raise ValueError(f"on_invalid={on_invalid!r}")
         if on_overflow not in ("raise", "shed"):
             raise ValueError(f"on_overflow={on_overflow!r}")
+        with span("engine.submit") as sp:
+            res = self._route(events, on_invalid)
+            sp.set_metadata(n=res.handed_in)
+        if res.rejected and on_overflow == "raise":
+            raise Backpressure(res.admitted, res.rejected,
+                               res.first_rejected_seqno, self.n_pending)
+        return res
+
+    def _route(self, events: Iterable[Event],
+               on_invalid: str) -> AdmissionResult:
+        """`submit`'s legacy dedup, global seqnos and routing; each
+        event goes to its owner shard's `StreamingEngine._admit`."""
         res = AdmissionResult()
         for ev in events:
             explicit = ev.seqno >= 0
@@ -1342,13 +1401,10 @@ class ShardedStreamingEngine:
                     continue
                 ev = dataclasses.replace(ev, seqno=self._next_seqno)
                 self._next_seqno += 1
-            res.merge(sh.submit(
+            res.merge(sh._admit(
                 [dataclasses.replace(
                     ev, user=int(self.spec.local_row(ev.user)))],
-                on_invalid=on_invalid, on_overflow="shed"))
-        if res.rejected and on_overflow == "raise":
-            raise Backpressure(res.admitted, res.rejected,
-                               res.first_rejected_seqno, self.n_pending)
+                on_invalid))
         return res
 
     def add_basket(self, user: int, items: Sequence[int]) -> None:
@@ -1383,29 +1439,39 @@ class ShardedStreamingEngine:
                 f"user {user} outside the deployment's "
                 f"[0, {self.spec.n_users}) global range")
         t0 = time.perf_counter()
-        self.run_until_drained()
-        sh = self.shards[self.spec.shard_of(user)]
-        local = int(self.spec.local_row(user))
-        # device-side scalar read (see StreamingEngine.forget_user)
-        nb = int(jax.device_get(sh.store.state.n_baskets[local]))
-        first = self._next_seqno
-        if nb:
-            self.submit([Event(KIND_DEL_BASKET, user, pos=p)
-                         for p in range(nb - 1, -1, -1)])
-            self.run_until_drained()
-        sh._scrub_user(local)
-        purged = sh._purge_dead_letters(local)
-        kept = [(ev, why) for ev, why in self.dead_letter
-                if ev.user != user]
-        purged += len(self.dead_letter) - len(kept)
-        self.dead_letter.clear()
-        self.dead_letter.extend(kept)
+        with span("engine.forget", user=user) as sp:
+            with span("engine.forget.drain"):
+                self.run_until_drained()
+            sh = self.shards[self.spec.shard_of(user)]
+            local = int(self.spec.local_row(user))
+            # device-side scalar read (see StreamingEngine.forget_user)
+            with span("engine.forget.lookup"):
+                nb = int(jax.device_get(sh.store.state.n_baskets[local]))
+            sp.set_metadata(baskets=nb)
+            first = self._next_seqno
+            if nb:
+                with span("engine.forget.emit"):
+                    self.submit([Event(KIND_DEL_BASKET, user, pos=p)
+                                 for p in range(nb - 1, -1, -1)])
+                with span("engine.forget.delete"):
+                    self.run_until_drained()
+            with span("engine.forget.scrub"):
+                sh._scrub_user(local)
+            with span("engine.forget.purge"):
+                purged = sh._purge_dead_letters(local)
+                kept = [(ev, why) for ev, why in self.dead_letter
+                        if ev.user != user]
+                purged += len(self.dead_letter) - len(kept)
+                self.dead_letter.clear()
+                self.dead_letter.extend(kept)
+            latency_s = time.perf_counter() - t0
+            with span("engine.forget.residue"):
+                residue = sh.store.row_residue([local])
         return ForgetReceipt(
             user=user, n_baskets_deleted=nb,
             seqnos=tuple(range(first, first + nb)),
-            purged_dead_letters=purged,
-            latency_s=time.perf_counter() - t0,
-            residue=sh.store.row_residue([local]))
+            purged_dead_letters=purged, latency_s=latency_s,
+            residue=residue)
 
     # -- micro-batch processing -----------------------------------------------
 
@@ -1418,25 +1484,12 @@ class ShardedStreamingEngine:
         (`_prepare_step`, async), then every shard blocks on its ONE
         summary fetch and applies (`_complete_step`), then the log
         advances — so no shard's transfer delays another shard's
-        dispatch.  Each shard's ``last_batch_seconds`` covers only its
-        own phase durations, not the other shards' syncs.
+        dispatch.  One ``engine.step`` span covers all three phases.
         """
-        prepped = []
-        for sh in self.shards:
-            t0 = time.perf_counter()
-            prep = sh._prepare_step()
-            prepped.append((sh, prep, time.perf_counter() - t0))
-        begun = []
-        for sh, prep, dt in prepped:
-            t0 = time.perf_counter()
-            evs = sh._complete_step(prep)
-            begun.append((sh, evs, dt + time.perf_counter() - t0))
-        total = 0
-        for sh, evs, own_dt in begun:
-            if evs:
-                # shift the start so elapsed = own phases + own finish
-                total += sh._finish_step(evs, time.perf_counter() - own_dt)
-        return total
+        with span("engine.step"):
+            prepped = [(sh, sh._prepare_step()) for sh in self.shards]
+            begun = [(sh, sh._complete_step(prep)) for sh, prep in prepped]
+            return sum(sh._finish_step(evs) for sh, evs in begun if evs)
 
     def run_until_drained(self, max_batches: int = 10_000) -> int:
         """Step all shards until no shard has pending events."""
