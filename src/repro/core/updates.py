@@ -15,6 +15,8 @@ point runs exactly one update rule:
     into ``uv_scale`` (DESIGN.md §3.5).
   * ``apply_del_item_batch``   — Eq. 13 + basket-vanish fallback, same
     sparse treatment (the in-place branch touches ONE cell per table).
+  * ``clear_users``            — a whole-user forget: writes the
+    empty-history row in one program (DESIGN.md §11.3), no update rule.
 
 ``apply_update_batch`` keeps the mixed-batch signature by partitioning
 on the host; ``apply_update_batch_dense`` is the seed's
@@ -947,6 +949,28 @@ def refresh_users(state: StreamState, users, params: TifuParams) -> StreamState:
         group_sizes=state.group_sizes,
         n_baskets=state.n_baskets,
         n_groups=state.n_groups,
+        err_mult=state.err_mult.at[users].set(1.0),
+        uv_scale=state.uv_scale.at[users].set(1.0),
+        lgv_scale=state.lgv_scale.at[users].set(1.0),
+    )
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def clear_users(state: StreamState, users) -> StreamState:
+    """Write the empty-history row for selected users (a forget).
+
+    What deleting every basket and then `refresh_users` leave, in one
+    program: history PAD, no baskets or groups, zero vectors, error
+    tracker and scales at 1.  O(|users| · (N·B + n_items)) writes.
+    """
+    return StreamState(
+        n_real=state.n_real,
+        user_vecs=state.user_vecs.at[users].set(0.0),
+        last_group_vecs=state.last_group_vecs.at[users].set(0.0),
+        history=state.history.at[users].set(PAD_ID),
+        group_sizes=state.group_sizes.at[users].set(0),
+        n_baskets=state.n_baskets.at[users].set(0),
+        n_groups=state.n_groups.at[users].set(0),
         err_mult=state.err_mult.at[users].set(1.0),
         uv_scale=state.uv_scale.at[users].set(1.0),
         lgv_scale=state.lgv_scale.at[users].set(1.0),

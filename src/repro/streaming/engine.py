@@ -48,7 +48,8 @@ from repro.core.types import (KIND_ADD_BASKET, KIND_DEL_BASKET,
 from repro.core.updates import (SCALE_CEIL, SCALE_FLOOR,
                                 apply_add_batch_counted,
                                 apply_del_basket_batch, apply_del_item_batch,
-                                refresh_users, renormalize_users)
+                                clear_users, refresh_users,
+                                renormalize_users)
 from repro.kernels import tile_plan
 from repro.parallel.sharding import UserShardSpec
 from repro.streaming.async_checkpoint import AsyncCheckpointer
@@ -206,8 +207,8 @@ class Event:
 class ForgetReceipt:
     """Receipt of one ``forget_user`` call (the GDPR front door).
 
-    ``seqnos`` are the deletion events emitted on the user's behalf (the
-    audit trail tying the forget to the exactly-once log),
+    ``seqnos`` are the exactly-once log entries the forget consumed, one
+    per deleted basket (the audit trail tying the forget to the log),
     ``purged_dead_letters`` the quarantined events of theirs that were
     dropped, and ``residue`` the post-scrub :meth:`StateStore.row_residue`
     measurement — ``clean`` is True iff every artifact reads zero.  The
@@ -271,6 +272,9 @@ class EngineMetrics:
     # events shed by the pending-queue high-water mark (never delivered;
     # the source resends them once the queues drain)
     backpressure_rejections: int = 0
+    # forgets of a user with baskets, each applied as one `clear_users`
+    # program (DESIGN.md §11.3) rather than a step per deleted basket
+    forget_clears: int = 0
 
 
 class StreamingEngine:
@@ -577,16 +581,18 @@ class StreamingEngine:
         """Erase ``user``'s entire history and every live trace of it.
 
         The GDPR right-to-be-forgotten front door: drains the pending
-        queues (so the user's in-flight events land first), emits one
-        ``KIND_DEL_BASKET`` per remaining basket — last position first,
-        so every position stays valid — through the normal exactly-once
-        path, then scrubs the float dust the deletion arithmetic may
-        leave outside the final support (`_scrub_user`) and purges the
-        user's dead-letter entries (quarantined events carry payloads —
-        residue too).  Synchronous: returns only after the state is
-        clean, with a :class:`ForgetReceipt` tying the emitted seqnos to
-        the measured residue.  Cost: the user's O(n_baskets) deletion
-        events plus one O(n_items) row scrub.  Idempotent.
+        queues (so the user's in-flight events land first), reads the
+        user's basket count, then applies the whole forget as ONE device
+        program (`_clear_user`): the row becomes the empty-history row
+        and the ``n_baskets`` seqnos of the deletions it stands for are
+        logged processed.  Then it refreshes the serving caches
+        (`StateStore.scrub_rows`) and purges the user's dead-letter
+        entries (quarantined events carry payloads — residue too).
+        Synchronous: returns only after the state is clean, with a
+        :class:`ForgetReceipt` tying the consumed seqnos to the measured
+        residue.  Cost: one drain, one scalar fetch, one O(N·B +
+        n_items) row write and one O(n_items) cache refresh, whatever
+        the history length.  Idempotent.
         """
         t0 = time.perf_counter()
         with span("engine.forget", user=user) as sp:
@@ -598,41 +604,47 @@ class StreamingEngine:
             with span("engine.forget.lookup"):
                 nb = int(jax.device_get(self.store.state.n_baskets[user]))
             sp.set_metadata(baskets=nb)
-            first = self._next_seqno
-            if nb:
-                with span("engine.forget.emit"):
-                    self.submit([Event(KIND_DEL_BASKET, user, pos=p)
-                                 for p in range(nb - 1, -1, -1)])
-                with span("engine.forget.delete"):
-                    self.run_until_drained()
+            seqnos = range(self._next_seqno, self._next_seqno + nb)
+            with span("engine.forget.clear", baskets=nb):
+                self._clear_user(user, seqnos)
             with span("engine.forget.scrub"):
-                self._scrub_user(user)
+                self.store.scrub_rows([user])
             with span("engine.forget.purge"):
                 purged = self._purge_dead_letters(user)
             latency_s = time.perf_counter() - t0
             with span("engine.forget.residue"):
                 residue = self.store.row_residue([user])
         return ForgetReceipt(
-            user=user, n_baskets_deleted=nb,
-            seqnos=tuple(range(first, first + nb)),
+            user=user, n_baskets_deleted=nb, seqnos=tuple(seqnos),
             purged_dead_letters=purged, latency_s=latency_s,
             residue=residue)
 
-    def _scrub_user(self, user: int) -> None:
-        """Zero a forgotten user's row exactly, caches included.
+    def _clear_user(self, user: int, seqnos: range) -> None:
+        """Apply a forget of local row ``user`` as one device program.
 
-        The deletion arithmetic zeroes the support cells of the final
-        history exactly (scenario 3 scatters the exact negation), but
-        earlier item deletes can leave f32 dust at cells OUTSIDE that
-        support — `refresh_users` on the now-empty history recomputes
-        the row from the integer leaves alone: exact zeros, scales
-        reset to 1.  `scrub_rows` then pushes the zeros into whichever
-        serving caches exist.
+        ``clear_users`` writes the row that deleting every basket and
+        rebuilding from the emptied history gives (exact zeros, history
+        PAD, scales and error tracker at 1; DESIGN.md §11.3), which
+        also removes any f32 dust earlier item deletes left outside the
+        support.  The ``seqnos``, one per deleted basket, are logged as
+        delivered and processed, as the per-basket deletions were, so
+        replays of them dedup and checkpoints carry the same log.  The
+        forget buffers nothing, so the high-water mark does not apply;
+        an open shed gap refuses it before anything changes, as it
+        refuses any fresh seqno (`_would_shed`).
         """
-        rows = jnp.asarray([user], jnp.int32)
-        self.store.state = refresh_users(self.store.state, rows,
-                                         self.params)
-        self.store.scrub_rows([user])
+        if seqnos and self._shed_from is not None:
+            raise Backpressure(0, len(seqnos), None, self._n_pending)
+        self.store.state = clear_users(self.store.state,
+                                       jnp.asarray([user], jnp.int32))
+        if not seqnos:
+            return
+        self._next_seqno = max(self._next_seqno, seqnos.stop)
+        self._max_delivered = max(self._max_delivered, seqnos.stop - 1)
+        self._processed_above.update(seqnos)
+        self._advance_watermark()
+        self.metrics.events_processed += len(seqnos)
+        self.metrics.forget_clears += 1
 
     def _purge_dead_letters(self, user: int) -> int:
         """Drop the user's quarantined events (they carry payloads)."""
@@ -1426,12 +1438,12 @@ class ShardedStreamingEngine:
         """Erase global ``user``'s history and every live trace of it.
 
         Same contract as :meth:`StreamingEngine.forget_user`, with the
-        deletion events submitted THROUGH THE ROUTER: the router owns
-        the global seqno counter, and a shard-local submit would
-        self-assign seqnos that collide with future router-assigned
-        ones — silently deduping later legitimate traffic.  Scrubs at
-        the owner shard and purges both the router's dead letters
-        (global ids) and the shard's (local rows).
+        consumed seqnos taken from THE ROUTER's counter: the router owns
+        the global seqno counter, and a shard-local assignment would
+        collide with future router-assigned ones — silently deduping
+        later legitimate traffic.  Clears the row at the owner shard
+        and purges both the router's dead letters (global ids) and the
+        shard's (local rows).
         """
         if not 0 <= user < self.spec.n_users:
             raise InvalidEventError(
@@ -1448,15 +1460,12 @@ class ShardedStreamingEngine:
             with span("engine.forget.lookup"):
                 nb = int(jax.device_get(sh.store.state.n_baskets[local]))
             sp.set_metadata(baskets=nb)
-            first = self._next_seqno
-            if nb:
-                with span("engine.forget.emit"):
-                    self.submit([Event(KIND_DEL_BASKET, user, pos=p)
-                                 for p in range(nb - 1, -1, -1)])
-                with span("engine.forget.delete"):
-                    self.run_until_drained()
+            seqnos = range(self._next_seqno, self._next_seqno + nb)
+            with span("engine.forget.clear", baskets=nb):
+                sh._clear_user(local, seqnos)
+                self._next_seqno = seqnos.stop
             with span("engine.forget.scrub"):
-                sh._scrub_user(local)
+                sh.store.scrub_rows([local])
             with span("engine.forget.purge"):
                 purged = sh._purge_dead_letters(local)
                 kept = [(ev, why) for ev, why in self.dead_letter
@@ -1468,8 +1477,7 @@ class ShardedStreamingEngine:
             with span("engine.forget.residue"):
                 residue = sh.store.row_residue([local])
         return ForgetReceipt(
-            user=user, n_baskets_deleted=nb,
-            seqnos=tuple(range(first, first + nb)),
+            user=user, n_baskets_deleted=nb, seqnos=tuple(seqnos),
             purged_dead_letters=purged, latency_s=latency_s,
             residue=residue)
 

@@ -17,7 +17,7 @@ from repro.streaming import (Event, ShardedStreamingEngine, StateStore,
                              StoreConfig, StreamingEngine, spans)
 
 P = TifuParams(n_items=29, group_size=3)
-FORGET = ["drain", "lookup", "emit", "delete", "scrub", "purge", "residue"]
+FORGET = ["drain", "lookup", "clear", "scrub", "purge", "residue"]
 # a step's children, in order: the kind sub-batches build and apply in turn
 STEP = re.compile(r"^cut summary( fetch)?( maintain)?( build apply)*"
                   r"( invalidate log)?$")
@@ -147,15 +147,12 @@ def test_single_engine_spans_nest_with_their_ids(recorded):
     (forget,) = [r for r in recorded if r.name == "engine.forget"]
     assert forget.ids == {"user": 1, "baskets": 3}
     assert _short(forget) == ["forget." + p for p in FORGET]
-    drain, _, emit, delete = forget.children[:4]
-    # the drain applies user 4's queued add; the deletions take a step
-    # each (one event per user per micro-batch), then the empty step
+    drain, _, clear = forget.children[:3]
+    # the drain applies user 4's queued add, then the empty step; the
+    # three baskets go in one clear, with no step, submit or fetch
     assert [len(_all([s], "engine.log")) for s in drain.children] == [1, 0]
-    assert [c.name for c in emit.children] == ["engine.submit"]
-    assert emit.children[0].ids["n"] == 3
-    dels = [a.ids["kind"] for a in _all([delete], "engine.apply")]
-    assert dels == [KIND_DEL_BASKET] * 3
-    assert len(delete.children) == 4
+    assert clear.ids == {"baskets": 3}
+    assert clear.children == []
 
 
 def test_sharded_engine_opens_one_span_per_call(recorded):
@@ -173,6 +170,8 @@ def test_sharded_engine_opens_one_span_per_call(recorded):
     (forget,) = [r for r in recorded if r.name == "engine.forget"]
     assert forget.ids == {"user": 1, "baskets": 3}
     assert _short(forget) == ["forget." + p for p in FORGET]
+    assert forget.children[2].ids == {"baskets": 3}
+    assert forget.children[2].children == []
 
 
 def test_maintain_span_when_renormalization_triggers(recorded):

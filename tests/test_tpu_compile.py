@@ -14,14 +14,17 @@ import): only one process at a time may load the TPU library.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.types import lane_padded
+from repro.core.types import StreamState, lane_padded
+from repro.core.updates import clear_users
 from repro.kernels import ops
 from repro.kernels.knn_topk import knn_topk_dtiled
 from repro.kernels.serving_topn import blend_topn_onehot, blend_topn_rows
@@ -120,3 +123,21 @@ def test_blend_topn_rows_compiles(one_chip):
         _sds(one_chip, (64, N_COLS)),
         _sds(one_chip, (64, K, N_COLS)))
     assert "tpu_custom_call" in text
+
+
+def test_clear_users_writes_the_row_in_place(one_chip):
+    # a forget's one program: the donated leaves alias its outputs, and
+    # nothing larger than a row is copied (max_baskets 24, basket 20)
+    n, b, k = 24, 20, 4
+    st = StreamState(
+        _sds(one_chip, (N_USERS, N_COLS)), _sds(one_chip, (N_USERS, N_COLS)),
+        _sds(one_chip, (N_USERS, n, b), jnp.int32),
+        _sds(one_chip, (N_USERS, k), jnp.int32),
+        *[_sds(one_chip, (N_USERS,), jnp.int32)] * 2,
+        *[_sds(one_chip, (N_USERS,))] * 3, n_real=N_ITEMS)
+    text = clear_users.lower(st, _sds(one_chip, (1,), jnp.int32)) \
+        .compile().as_text()
+    assert "input_output_alias" in text
+    copies = re.findall(r"=\s*\w+\[([\d,]*)\]\S*\s+copy(?:-start)?\(", text)
+    sizes = [math.prod(int(d) for d in c.split(",") if d) for c in copies]
+    assert all(size < N_COLS for size in sizes), sizes
