@@ -8,7 +8,9 @@ place, one precision step down, must come out not correct.
 The configurations state float32 state.  The control replays the same
 schedule a run plays (every event due before the window's end applied,
 every forget due done) with its user vectors computed in bfloat16, and
-feeds what it produced to the same comparison as a run.  It prints each
+feeds what it produced to the same comparison as a run.  Each checked
+read is answered by the reference's prediction from those vectors, in
+float32, on the state with every event and forget due by then applied.  It prints each
 number beside its limit, per seed.  The benchmark's own runs never run
 it.
 """
@@ -26,12 +28,14 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import check  # noqa: E402
 import gen  # noqa: E402
+from reference import blended_scores, top_n  # noqa: E402
 
 
 @dataclasses.dataclass
 class _Player:
     ie: int
     forgets: list
+    kept: dict
 
 
 @dataclasses.dataclass
@@ -62,9 +66,30 @@ def control_program(cfg: dict, traffic: dict, hist, sched, seed: int,
     rows = ref.matrix(users, cfg["n_items"], cfg["r_b"], cfg["r_g"],
                       dtype=low).astype(np.float32)
     residue = [{"control": 0.0} for _ in forgets]
-    return _Program(seconds=seconds, player=_Player(n_ev, forgets),
+    return _Program(seconds=seconds,
+                    player=_Player(n_ev, forgets,
+                                   control_reads(cfg, traffic, hist, sched,
+                                                 seed, seconds, low)),
                     checked_users=users, rows=rows, residue=residue,
                     failed=0)
+
+
+def control_reads(cfg: dict, traffic: dict, hist, sched, seed: int,
+                  seconds: float, dtype) -> dict:
+    """The checked reads as the reference in ``dtype`` answers them."""
+    idx = check.checked_reads(sched, traffic["warm_s"], seconds,
+                              traffic.get("read_check", 0), seed)
+    reads = [check.Read(
+        int(sched.read_user[i]), None,
+        int(np.searchsorted(sched.ev_due, sched.read_due[i], "right")), {},
+        int(np.searchsorted(sched.forget_due, sched.read_due[i], "right")))
+        for i in idx]
+    for r, corpus, sqnorm in check.read_states(cfg, hist, sched, reads,
+                                               dtype):
+        r.ids = top_n(blended_scores(corpus, sqnorm, r.user,
+                                     cfg["k_neighbors"], cfg["alpha"]),
+                      traffic["read_topn"])
+    return dict(zip(idx.tolist(), reads))
 
 
 def main(argv=None) -> int:

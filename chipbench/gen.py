@@ -9,8 +9,8 @@ popularity is taken as the first ``k`` distinct draws of a
 with-replacement stream, which is the same distribution.  The two
 Poisson tails are cut at the configuration's caps.
 
-A schedule is every event and forget of one run, with its due time,
-built before the run from ``--seed``: the harness only replays it.
+A schedule is every event, forget and read of one run, with its due
+time, built before the run from ``--seed``: the harness only replays it.
 """
 from __future__ import annotations
 
@@ -142,7 +142,8 @@ class Schedule:
 
     ``ev_*`` are basket additions (``ev_pos == -1``, items in
     ``ev_items``) and basket deletions (``ev_pos >= 0``), sorted by due
-    time; forgets are their own fixed-interval stream.
+    time; forgets and single-user reads are their own fixed-interval
+    streams.
     """
 
     ev_due: np.ndarray
@@ -151,6 +152,8 @@ class Schedule:
     ev_pos: np.ndarray
     forget_due: np.ndarray
     forget_user: np.ndarray
+    read_due: np.ndarray
+    read_user: np.ndarray
 
 
 def _ticks(interval: float, t_end: float, phase: float) -> np.ndarray:
@@ -184,7 +187,10 @@ def schedule(cfg: dict, traffic: dict, hist: Histories, seed: int,
     baskets) are spread evenly over the run.  Forgets take distinct
     users, stratified over the basket-count distribution so every seed
     forgets the same spread of history lengths; forgotten users get no
-    other traffic.
+    other traffic.  Reads come ``read_rate`` a second (none without the
+    key), each of one user drawn in proportion to the user's generated
+    basket count, never a forgotten one; they take their own generator,
+    so the other streams are the same with reads or without.
     """
     rng = np.random.default_rng([seed, 1])
     n_users = cfg["n_users"]
@@ -268,9 +274,23 @@ def schedule(cfg: dict, traffic: dict, hist: Histories, seed: int,
     users = np.concatenate([add_user, del_user]).astype(np.int32)
     pos = np.concatenate([np.full(n_add, -1, np.int32), del_pos])
 
+    read_due, read_user = _reads(traffic, n_b, forget_user, seed, t_end)
     return Schedule(ev_due=due[srt], ev_user=users[srt], ev_items=items[srt],
                     ev_pos=pos[srt], forget_due=forget_due,
-                    forget_user=forget_user)
+                    forget_user=forget_user, read_due=read_due,
+                    read_user=read_user)
+
+
+def _reads(traffic: dict, n_b: np.ndarray, forgotten: np.ndarray, seed: int,
+           t_end: float) -> tuple:
+    """Due times and users of the read stream over ``[0, t_end)``."""
+    rate = traffic.get("read_rate", 0)
+    due = _ticks(1.0 / rate, t_end, 0.5) if rate > 0 else np.zeros(0)
+    weight = n_b.astype(np.float64)
+    weight[forgotten] = 0.0
+    rng = np.random.default_rng([seed, 3])
+    user = rng.choice(n_b.size, size=due.size, p=weight / weight.sum())
+    return due, user.astype(np.int32)
 
 
 def _capped_draw(rng, users, weight, n, cap) -> np.ndarray:

@@ -7,6 +7,9 @@ position ``p`` of group ``j`` (1-based, ``k`` groups, ``tau_j`` baskets
 in group ``j``) weighs ``r_b^(tau_j - p) / tau_j * r_g^(k - j) / k``.
 An addition opens a new group once the last holds ``group_size``
 baskets; a deletion shrinks its group, and a group left empty vanishes.
+
+A read's prediction (§2.2) blends the user's own vector with the mean
+of the ``k`` nearest other users' vectors by euclidean distance.
 """
 from __future__ import annotations
 
@@ -75,3 +78,63 @@ class Users:
         for r, u in enumerate(users):
             out[r, :n_items] = self.vector(int(u), n_items, r_b, r_g, dtype)
         return out
+
+
+def _distances(corpus: np.ndarray, sqnorm: np.ndarray, user: int):
+    """Squared euclidean distance of every row to ``user``'s, the user's
+    own at infinity."""
+    q = corpus[user]
+    nz = np.nonzero(q)[0]
+    dist = sqnorm - 2 * (corpus[:, nz] @ q[nz]) + sqnorm[user]
+    dist[user] = np.inf
+    return dist
+
+
+def blended_scores(corpus: np.ndarray, sqnorm: np.ndarray, user: int,
+                   k: int, alpha: float) -> np.ndarray:
+    """``alpha`` times ``user``'s row plus ``1 - alpha`` times the mean of
+    the rows of its ``k`` nearest other users, in ``corpus``'s dtype.
+
+    ``corpus`` holds every user's vector, one row each, and ``sqnorm``
+    their squared norms.  Distances are euclidean; among equal distances
+    the lower user id is nearer.  ``k`` is at most the number of other
+    users.
+    """
+    dist = _distances(corpus, sqnorm, user)
+    k = max(1, min(k, corpus.shape[0] - 1))
+    nearest = np.argsort(dist, kind="stable")[:k]
+    return alpha * corpus[user] + (1 - alpha) * corpus[nearest].mean(axis=0)
+
+
+def blended_bounds(corpus: np.ndarray, sqnorm: np.ndarray, user: int,
+                   k: int, alpha: float, tie: float) -> tuple:
+    """The lowest and the highest blended score of each item over every
+    set of ``k`` nearest users that distances off by rounding could pick.
+
+    A user whose distance lies within ``tie * (|q|^2 + |c|^2)`` of the
+    k-th nearest's, that user's and the k-th's slack added, is a tie at
+    the k-th place: any of the ties may fill the places that the surely
+    nearer users leave.  Without such ties both bounds are
+    ``blended_scores``.
+    """
+    dist = _distances(corpus, sqnorm, user)
+    k = max(1, min(k, corpus.shape[0] - 1))
+    kth = np.argsort(dist, kind="stable")[k - 1]
+    slack = tie * (sqnorm + sqnorm[user])
+    ties = np.abs(dist - dist[kth]) <= slack + slack[kth]
+    sure = (dist < dist[kth]) & ~ties
+    free = k - int(sure.sum())
+    lo = corpus[sure].sum(axis=0)
+    hi = lo.copy()
+    cand = corpus[ties]
+    cols = np.flatnonzero(cand.any(axis=0))
+    cand = np.sort(cand[:, cols], axis=0)
+    lo[cols] += cand[:free].sum(axis=0)
+    hi[cols] += cand[cand.shape[0] - free:].sum(axis=0)
+    own = alpha * corpus[user]
+    return own + (1 - alpha) * lo / k, own + (1 - alpha) * hi / k
+
+
+def top_n(scores: np.ndarray, n: int) -> np.ndarray:
+    """The ``n`` best items, best first, lower id first among ties."""
+    return np.argsort(-scores, kind="stable")[:n]

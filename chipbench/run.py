@@ -16,9 +16,10 @@ the limits of the correctness check).  A run:
    starts the cell's engine and plays the first ``warm_s`` seconds of
    the schedule, so every shape the window uses is compiled: set-up;
 3. plays ``--seconds`` more of the schedule, open loop: additions and
-   deletions are submitted when due, due forgets (``forget_user``) are
-   served in due order, and the engine steps while anything is
-   pending.  Latencies run from due time;
+   deletions are submitted when due, due forgets (``forget_user``) and
+   single-user reads (``recommend``) are served in due order, and the
+   engine steps while anything is pending.  Latencies run from due
+   time;
 4. checks what the window produced against ``reference.py``
    (``check.py``) and prints one JSON line, last on stdout.
 
@@ -193,16 +194,24 @@ class Player:
 
     ``events`` are the schedule's prebuilt ``Event`` objects, in due
     order.  Each pass of the loop submits every due event, serves a due
-    forget, then steps the engine if anything is pending, and sleeps
-    until the next due time otherwise.
+    forget or else every due read, then steps the engine if anything is
+    pending, and sleeps until the next due time otherwise.  A read asks
+    for ``topn`` items (the traffic's ``read_topn``, None without reads).
+    The reads whose schedule index is in ``keep`` keep their answer and
+    the state they saw for the check.
     """
 
-    def __init__(self, eng, sched, events, traced: bool):
+    def __init__(self, eng, sched, events, traced: bool, topn, keep=()):
         self.eng, self.s, self.events = eng, sched, events
         self.traced = traced
+        self.topn = topn
+        self.keep = set(int(i) for i in keep)
         self.stalls = Stalls()
-        self.ie = self.jf = 0
+        self.ie = self.jf = self.jr = 0
         self.forgets: list = []    # (index, due, latency, steps, receipt)
+        self.reads: list = []      # (index, due, latency)
+        self.kept: dict = {}       # index -> check.Read
+        self.read_users = 0        # users served by reads
         self.lag: list = []        # (due, seconds late when handed over)
         self.rejected = 0
 
@@ -222,7 +231,7 @@ class Player:
 
     def run(self, t0: float, until: float, clock=time.perf_counter) -> None:
         s, eng = self.s, self.eng
-        n_ev, n_fg = s.ev_due.size, s.forget_due.size
+        n_ev, n_fg, n_rd = s.ev_due.size, s.forget_due.size, s.read_due.size
         while True:
             now = clock() - t0
             if now >= until:
@@ -239,12 +248,15 @@ class Player:
             if self.jf < n_fg and s.forget_due[self.jf] <= now:
                 self.forget(t0, clock)
                 continue
+            while self.jr < n_rd and s.read_due[self.jr] <= now:
+                self.read(t0, clock)
             if eng.n_pending:
                 with self.span("bench.step"):
                     eng.step()
                 continue
             nxt = min(s.ev_due[self.ie] if self.ie < n_ev else until,
                       s.forget_due[self.jf] if self.jf < n_fg else until,
+                      s.read_due[self.jr] if self.jr < n_rd else until,
                       until)
             pause = nxt - (clock() - t0)
             if pause > 0:
@@ -262,6 +274,23 @@ class Player:
                              self.eng.metrics.batches - b0, rc))
         self.jf += 1
 
+    def read(self, t0: float, clock=time.perf_counter) -> None:
+        i = self.jr
+        due = self.s.read_due[i]
+        user = int(self.s.read_user[i])
+        self.lag.append((due, clock() - t0 - due))
+        with self.span("bench.recommend"):
+            ids = self.eng.recommend([user], topn=self.topn)
+        self.reads.append((i, due, clock() - t0 - due))
+        self.read_users += 1
+        if i in self.keep:
+            # after the timed call: the host queues hold what the read's
+            # state did not yet have
+            pending = {u: len(q) for u, q in self.eng._queues.items()}
+            self.kept[i] = _import("check").Read(
+                user, np.asarray(ids[0]).copy(), self.ie, pending, self.jf)
+        self.jr += 1
+
     def catch_up(self, t0: float, until: float) -> None:
         """Hand over everything due before ``until``."""
         j = int(np.searchsorted(self.s.ev_due, until))
@@ -273,11 +302,19 @@ class Player:
         self.finish_due(t0, until)
 
     def finish_due(self, t0: float, until: float) -> None:
-        """Serve the forgets that fell due before ``until`` and were not
-        reached: their latency counts the wait."""
-        while self.jf < self.s.forget_due.size and \
-                self.s.forget_due[self.jf] < until:
-            self.forget(t0)
+        """Serve the forgets and reads that fell due before ``until`` and
+        were not reached, in due order: their latency counts the wait."""
+        s = self.s
+        while True:
+            fg = s.forget_due[self.jf] if self.jf < s.forget_due.size \
+                else until
+            rd = s.read_due[self.jr] if self.jr < s.read_due.size else until
+            if min(fg, rd) >= until:
+                return
+            if fg <= rd:
+                self.forget(t0)
+            else:
+                self.read(t0)
 
 
 def build_events(sched, Event, kinds) -> list:
@@ -368,8 +405,12 @@ def run_program(cfg: dict, traffic: dict, seed: int, seconds: float,
     # compiles the steady shapes, the second starts from an empty queue
     # as the window does and compiles the shapes of that start
     eng = StreamingEngine(store, params, batch_size=cfg["batch_size"])
-    drv = Player(eng, sched, events, traced)
     warm = traffic["warm_s"]
+    from check import checked_reads, checked_users
+    keep = checked_reads(sched, warm, seconds, traffic.get("read_check", 0),
+                         seed)
+    drv = Player(eng, sched, events, traced,
+                 topn=traffic.get("read_topn"), keep=keep)
     t, k = time.perf_counter(), log.programs
     for lo, hi in ((0.0, warm / 2), (warm / 2, warm)):
         t0 = time.perf_counter() - lo
@@ -381,6 +422,7 @@ def run_program(cfg: dict, traffic: dict, seed: int, seconds: float,
     t0 = time.perf_counter() - warm
 
     c0, k0 = counters(eng), log.snapshot()
+    r0 = drv.read_users
     drv.stalls = Stalls()
     gc.callbacks.append(drv.stalls.gc_callback)
     if traced:
@@ -395,6 +437,7 @@ def run_program(cfg: dict, traffic: dict, seed: int, seconds: float,
     gc.callbacks.remove(drv.stalls.gc_callback)
     stalls = drv.stalls.report()
     c1, k1 = counters(eng), log.snapshot()
+    c0["read_users"], c1["read_users"] = r0, drv.read_users
     window_compiled = log.names[k0["names"]:k1["names"]]
     if traced:
         jax.profiler.stop_trace()
@@ -408,7 +451,6 @@ def run_program(cfg: dict, traffic: dict, seed: int, seconds: float,
     eng.run_until_drained(max_batches=1 << 30)
     failed += (drv.rejected + eng.metrics.dead_letters
                + eng.metrics.dropped_adds)
-    from check import checked_users
     users = checked_users(cfg, sched, drv.ie, seed)
     take = jax.jit(lambda st, idx: st.materialized_user_vecs()[idx])
     rows = np.asarray(take(store.state, jnp.asarray(users, jnp.int32)))
@@ -443,6 +485,7 @@ class Ctx:
     counters: dict
     forget_ms: list
     forget_steps: list
+    read_ms: list
     trace: object
     peaks: dict
 
@@ -514,6 +557,7 @@ def run(argv=None, require_tpu: bool = True, spec=None) -> int:
     in_window = (lambda due: warm <= due < warm + args.seconds)
     forget_ms = [f[2] * 1e3 for f in drv.forgets if in_window(f[1])]
     forget_steps = [f[3] for f in drv.forgets if in_window(f[1])]
+    read_ms = [r[2] * 1e3 for r in drv.reads if in_window(r[1])]
     lag = np.asarray([x[1] for x in drv.lag if in_window(x[0])] or [0.0])
 
     print(f"device: {len(devices)} x {kind}; compile cache {CACHE_DIR}")
@@ -531,7 +575,14 @@ def run(argv=None, require_tpu: bool = True, spec=None) -> int:
           f"max {lag.max():.6f}")
     print(f"backlog at window end: {prog.backlog} events; window counters "
           f"{json.dumps(prog.counters)}")
-    print(f"latency histogram ms: forgets [{histogram(forget_ms)}]")
+    print(f"latency histogram ms: forgets [{histogram(forget_ms)}]; "
+          f"reads [{histogram(read_ms)}]")
+    if read_ms:
+        print(f"reads: {len(read_ms)} due in window, "
+              f"{prog.counters['read_users'] / prog.window_s:.3f}/s served "
+              f"in it; ms p50 {np.percentile(read_ms, 50):.6f} p95 "
+              f"{np.percentile(read_ms, 95):.6f} p99 "
+              f"{np.percentile(read_ms, 99):.6f} max {max(read_ms):.6f}")
     print(f"stalls in window: {prog.stalls}")
     sys.stdout.flush()
 
@@ -547,7 +598,7 @@ def run(argv=None, require_tpu: bool = True, spec=None) -> int:
         print("trace: programs by device time " + json.dumps(top))
     ctx = Ctx(cfg=cfg, setup_s=prog.setup_s, window_s=prog.window_s,
               counters=prog.counters, forget_ms=forget_ms,
-              forget_steps=forget_steps, trace=trace,
+              forget_steps=forget_steps, read_ms=read_ms, trace=trace,
               peaks=peaks.get(kind))
     metrics = {}
     for m in cell_metrics(bench, args.workload, bool(args.trace)):
@@ -562,7 +613,7 @@ def run(argv=None, require_tpu: bool = True, spec=None) -> int:
     correct = all(n["value"] <= n["limit"] for n in numbers.values())
     attempted = (int(np.sum((sched.ev_due >= warm)
                             & (sched.ev_due < warm + args.seconds)))
-                 + len(forget_ms))
+                 + len(forget_ms) + len(read_ms))
     device = {"platform": devices[0].platform, "kind": kind,
               "count": chips, "memory_peak_bytes": prog.memory_peak_bytes}
     if trace is not None:

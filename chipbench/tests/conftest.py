@@ -35,6 +35,9 @@ def tiny_spec(cell: str) -> tuple:
                    del_user_frac=traffic["del_user_frac"] and 0.05)
     if traffic["forget_interval_s"]:
         traffic["forget_interval_s"] = 0.5
+    if traffic.get("read_rate"):
+        traffic.update(read_rate=min(traffic["read_rate"], 40.0),
+                       read_check=min(traffic["read_check"], 24))
     return cfg, traffic, 1, bench
 
 

@@ -6,7 +6,7 @@ import check
 import control
 import gen
 
-CELLS = ["tafeng.ingest", "valuedshopper.forget"]
+CELLS = ["tafeng.ingest", "valuedshopper.forget", "tafeng.serve"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -18,3 +18,6 @@ def test_control_fails_a_number(spec, cell):
     prog = control.control_program(cfg, traffic, hist, sched, 5, seconds)
     numbers = check.compare(cfg, traffic, hist, sched, prog)
     assert any(n["value"] > n["limit"] for n in numbers.values()), numbers
+    if "read_rate" in traffic:
+        # the reads' own number fails too, not only the state's
+        assert numbers["read_err"]["value"] > numbers["read_err"]["limit"]

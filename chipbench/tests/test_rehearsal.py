@@ -11,7 +11,7 @@ import pytest
 
 import run
 
-CELLS = ["tafeng.ingest", "valuedshopper.forget"]
+CELLS = ["tafeng.ingest", "valuedshopper.forget", "tafeng.serve"]
 
 
 def _run(spec, cell, capsys, seed=3, trace=0):
@@ -29,14 +29,20 @@ def test_cell_runs_correct(spec, cell, capsys):
     assert out["failed"] == 0 and out["attempted"] > 0
     assert list(out)[-1] == "checks"
     assert "setup_s" in out["metrics"]
+    if cell == "tafeng.serve":
+        assert out["checks"]["read_err"]["value"] == 0.0
+        assert out["metrics"]["read_p50_ms"]["value"] > 0
 
 
-def test_traced_run_reports_counters(spec, capsys):
+@pytest.mark.parametrize("cell,counted", [
+    ("tafeng.ingest", {"events_per_step.ingest"}), ("tafeng.serve", set())])
+def test_traced_run_reports_counters(spec, cell, counted, capsys):
     # on the CPU there is no device plane: the trace readers find
     # nothing and stay silent, the counter readers still report
-    out = _run(spec, "tafeng.ingest", capsys, trace=1)
-    assert set(out["metrics"]) == {"events_per_step.ingest"}
+    out = _run(spec, cell, capsys, trace=1)
+    assert set(out["metrics"]) == counted
     assert out["device"]["window_s"] > 0
+    assert out["correct"], out["checks"]
 
 
 def _noop(self, *a, **k):
@@ -56,6 +62,9 @@ def _half_batch(orig):
     ("tafeng.ingest", "corrupt_row"),
     ("valuedshopper.forget", "state_unchanged"),
     ("valuedshopper.forget", "delete_applier_off"),
+    ("tafeng.serve", "state_unchanged"),
+    ("tafeng.serve", "half_batch"),
+    ("tafeng.serve", "answer_altered"),
 ])
 def test_fault_makes_run_incorrect(spec, cell, fault, capsys, monkeypatch):
     import dataclasses
@@ -97,5 +106,17 @@ def test_fault_makes_run_incorrect(spec, cell, fault, capsys, monkeypatch):
             return dataclasses.replace(out, user_vecs=out.user_vecs.at[
                 rows].multiply(1.001, mode="drop"))
         monkeypatch.setattr(engine, "apply_del_basket_batch", off)
+    elif fault == "answer_altered":
+        # each read's best item is replaced by the next item id
+        orig = StreamingEngine.recommend
+
+        def altered(self, *a, **k):
+            out = orig(self, *a, **k).copy()
+            out[:, 0] = (out[:, 0] + 1) % self.store.cfg.n_items
+            return out
+        monkeypatch.setattr(StreamingEngine, "recommend", altered)
     out = _run(spec, cell, capsys)
     assert not out["correct"], out["checks"]
+    if fault == "answer_altered":
+        assert out["checks"]["read_err"]["value"] > out["checks"][
+            "read_err"]["limit"]

@@ -153,7 +153,7 @@ def test_engine_spans_leave_the_accepted_readings_as_they_are():
                ("jit_apply_del_basket_batch(8)", 70, 4),
                ("jit_apply_del_basket_batch(8)", 80, 4)]
     bench_only = [h for h in HOST if h[0].startswith("bench.")]
-    readers = ("apply_add_ms", "apply_del_ms", "idle_share.ingest",
+    readers = ("apply_add_ms", "idle_share.ingest",
                "idle_share.forget", "events_per_step.ingest",
                "forget_steps")
     seen = []
