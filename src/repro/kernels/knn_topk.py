@@ -384,6 +384,8 @@ register(KernelContract(
     accumulators=("float32", "float32", "float32", "int32", "float32",
                   "int32"),
     vmem_model=_avmem.knn_topk_dtiled_block_bytes,
-    max_shapes={"d": 1 << 20, "k": 512, "bq": 128, "bm": 512,
+    # k up to 1024 covers the paper's Instacart k = 900, wider than a
+    # corpus tile: the running list fills over several tiles
+    max_shapes={"d": 1 << 20, "k": 1024, "bq": 128, "bm": 512,
                 "bd": 512},
 ))

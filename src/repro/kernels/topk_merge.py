@@ -39,6 +39,12 @@ def merge_topk(acc_vals, acc_idx, new_vals, new_idx, tile_vals, base,
     ``[bq, bt]``) holds the tile's scores, whose indices are
     ``base + lane``.  ``new_vals``/``new_idx`` are scratch refs shaped
     like the running list.  Cost: ``k`` passes over ``kw + bt`` lanes.
+
+    The running list may be wider than a tile (``k > bt``, as at
+    Instacart's k = 900 against 512-row corpus tiles): after the first
+    merges its unfilled slots hold -inf scores (index 0, or a masked
+    column's) and rank below every finite candidate, so a later tile's
+    finite scores replace them.
     """
     bq, kw = acc_vals.shape
     bt = tile_vals.shape[1]

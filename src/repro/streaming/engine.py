@@ -1080,32 +1080,44 @@ class StreamingEngine:
         (row-invalidated alongside the fp32 one) through
         `core.knn.recommend_for_users_quant` — VMEM flat in n_items,
         ¼ the HBM bytes, euclidean only.
+
+        Spans (DESIGN.md §12.3): ``engine.recommend`` (ids: real users,
+        bucket, k) holds ``.corpus`` (the cache's dirty-row refresh),
+        ``.dispatch`` (the serving program's call) and ``.fetch`` (the
+        wait for its answer).
         """
-        ids, q_n, bucket = _pad_request(user_ids)
-        if q_n == 0:
-            return np.zeros((0, topn), np.int32)
-        k = self.params.k_neighbors if k is None else k
-        alpha = self.params.alpha if alpha is None else alpha
-        if quantized:
-            if metric != "euclidean":
+        with span("engine.recommend") as sp:
+            ids, q_n, bucket = _pad_request(user_ids)
+            if q_n == 0:
+                return np.zeros((0, topn), np.int32)
+            k = self.params.k_neighbors if k is None else k
+            alpha = self.params.alpha if alpha is None else alpha
+            sp.set_metadata(users=q_n, bucket=bucket, k=k)
+            if quantized and metric != "euclidean":
                 raise ValueError("quantized serving is euclidean-only")
-            corpus_q, c_scale = self.store.quantized_corpus()
-            recs = knn.recommend_for_users_quant(
-                corpus_q, c_scale, jnp.asarray(ids.astype(np.int32)),
-                k=k, alpha=alpha, topn=topn, n_items=self.store.cfg.n_items)
-        else:
-            recs = knn.recommend_for_users(
-                self.store.corpus(), jnp.asarray(ids.astype(np.int32)),
-                k=k, alpha=alpha, topn=topn, metric=metric,
-                n_items=self.store.cfg.n_items)
-        self.metrics.serve_requests += 1
-        # alpha included: it is a static (compile-triggering) arg of
-        # the Pallas serving path, so per-request alphas must show up
-        # in the gated compiled-shape count
-        self._serve_shapes.add((bucket, topn, k, float(alpha), metric,
-                                quantized))
-        self.metrics.serve_compiled_shapes = len(self._serve_shapes)
-        return np.asarray(recs)[:q_n]
+            rows = jnp.asarray(ids.astype(np.int32))
+            n_items = self.store.cfg.n_items
+            with span("engine.recommend.corpus"):
+                corpus = (self.store.quantized_corpus() if quantized
+                          else self.store.corpus())
+            with span("engine.recommend.dispatch"):
+                if quantized:
+                    recs = knn.recommend_for_users_quant(
+                        *corpus, rows, k=k, alpha=alpha, topn=topn,
+                        n_items=n_items)
+                else:
+                    recs = knn.recommend_for_users(
+                        corpus, rows, k=k, alpha=alpha, topn=topn,
+                        metric=metric, n_items=n_items)
+            self.metrics.serve_requests += 1
+            # alpha included: it is a static (compile-triggering) arg of
+            # the Pallas serving path, so per-request alphas must show
+            # up in the gated compiled-shape count
+            self._serve_shapes.add((bucket, topn, k, float(alpha), metric,
+                                    quantized))
+            self.metrics.serve_compiled_shapes = len(self._serve_shapes)
+            with span("engine.recommend.fetch"):
+                return np.asarray(recs)[:q_n]
 
     def freeze_serving(self) -> None:
         """Enter degraded serving: pin the current corpus snapshot.

@@ -195,6 +195,23 @@ def test_maintain_span_when_renormalization_triggers(recorded):
         assert [c.name for c in m.children] == ["engine.fetch"]
 
 
+def test_recommend_spans_nest_with_their_ids(recorded):
+    eng = _single()
+    _drive(eng)
+    del recorded[:]
+    eng.recommend([0, 3, 5], topn=4)
+    eng.recommend([], topn=4)
+    full, empty = recorded
+    assert full.name == empty.name == "engine.recommend"
+    # three real users pad to a bucket of 4
+    assert full.ids == {"users": 3, "bucket": 4, "k": P.k_neighbors}
+    assert _short(full) == ["recommend.corpus", "recommend.dispatch",
+                            "recommend.fetch"]
+    assert all(c.children == [] for c in full.children)
+    # an empty request serves nothing
+    assert empty.ids == {} and empty.children == []
+
+
 def test_gc_span_nests_in_the_open_span(recorded):
     _single()        # installs the collector hook
     with spans.span("outer"):

@@ -6,8 +6,9 @@ primitives, more scoped VMEM than a kernel may use.  These tests compile
 each main-path kernel for a described (not attached) ``v5e:2x2`` chip at
 the shapes ``chip_smoke.py`` runs: the paper's TaFeng deployment (13,949
 users, 11,997 items padded to 12,032 columns, k = 300), 256-user update
-and request batches.  Nothing runs, so nothing here says anything about
-results or times.
+and request batches, and one read of the Instacart deployment at
+k = 900.  Nothing runs, so nothing here says anything about results or
+times.
 
 The topology is described inside a module-scoped fixture (never at
 import): only one process at a time may load the TPU library.
@@ -104,6 +105,24 @@ def test_stage_a_compiles_at_deployment_width(one_chip):
         _sds(one_chip, (N_USERS, N_COLS)),
         _sds(one_chip, (BATCH,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_instacart_read_compiles_at_k900(one_chip):
+    # the paper's Instacart deployment (19,935 users, 7,999 items padded
+    # to 8,064 columns, k = 900, alpha 0.9): one single-user read's
+    # program, the D-tiled stage A with a running list wider than a
+    # corpus tile, then the one-hot stage B
+    n_users, n_items, k = 19_935, 7_999, 900
+    n_cols = lane_padded(n_items)
+    bd = ops.stage_a_bd(n_cols, k)
+    assert bd is not None
+    text = _compiled_text(
+        lambda c, u: ops._fused_recommend_dtiled_pallas(
+            c, u, k=k, alpha=0.9, topn=TOPN, bd=bd, n_items=n_items,
+            interpret=False),
+        _sds(one_chip, (n_users, n_cols)),
+        _sds(one_chip, (1,), jnp.int32))
+    assert text.count("tpu_custom_call") >= 2
 
 
 def test_blend_topn_onehot_compiles(one_chip):
